@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 
 from benchmarks import common, tables
+from repro.launch.cache import enable_compile_cache
 
 TABLES = [
     "1", "2", "3", "4", "5", "6", "7", "8", "9", "10", "11", "12", "13", "14",
@@ -31,6 +32,7 @@ def main() -> None:
         help="path for the JSON record dump (default BENCH_<scale>.json)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_self = {"tiny": 2000, "small": 20000, "medium": 100000, "paper": 500000}[args.scale]
     n_chain = {"tiny": 1500, "small": 8000, "medium": 40000, "paper": 500000}[args.scale]

@@ -33,7 +33,8 @@ V-SHARD-PARTITION  per-shard CSR key ranges exactly partition the
                    domain; edge slices are contiguous and exhaustive
 V-SHARD-TILE  padded tile covers every shard's real range width
 V-SENTINEL    pad sentinels sit outside every real key range
-V-KERN        fused-hop kernel configs: tile sizes are positive
+V-KERN        fused-hop kernel configs: some tile fits VMEM, tile
+              sizes are positive
               multiples of the k-step granule, the segment space keeps
               the pad sentinels non-aliasing (int32 headroom), and the
               accumulator dtype is a float type the semirings support
@@ -645,7 +646,8 @@ def check_kernels(plan) -> list[Diagnostic]:
     """V-KERN: every per-hop fused-megakernel config is executable.
 
     Checks the deterministic (model-ranked) configs the fused path would
-    launch with: tile sizes must be positive multiples of the k-step
+    launch with: some candidate tile must fit VMEM (execution raises
+    otherwise), tile sizes must be positive multiples of the k-step
     granule (``fused_hop`` splits tiles into granule-wide slices; a
     non-divisible tile silently drops trailing slices — the
     ``math.gcd`` regression), the hop's segment space must leave the
@@ -660,6 +662,9 @@ def check_kernels(plan) -> list[Diagnostic]:
     for entry in autotune.plan_kernel_configs(plan.prep, k=k):
         cfg = entry["config"]
         site = f"kernel/{entry['rel']}"
+        if cfg is None:
+            out.append(Diagnostic("V-KERN", site, entry["no_fit"]))
+            continue
         for name in ("block_e", "block_s", "block_r"):
             v = getattr(cfg, name)
             if v <= 0 or v % _KSTEP_GRANULE:

@@ -495,6 +495,9 @@ class Plan:
         ]
         for entry in autotune.plan_kernel_configs(self.prep, k=k):
             cfg = entry["config"]
+            if cfg is None:
+                lines.append(f"  {entry['rel']}: {entry['no_fit']}")
+                continue
             lines.append(
                 f"  {entry['rel']}: tiles {cfg.key()}  "
                 f"segs={entry['num_segments']}  acc={entry['acc_dtype']}  "

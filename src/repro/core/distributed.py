@@ -36,12 +36,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.4.35 re-exports shard_map; keep the experimental fallback
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map
 
 from repro.core.jax_engine import EDGE_BUCKET, _INT32_LIMIT
 from repro.core.prepare import Prepared, _ravel, csr_restrict

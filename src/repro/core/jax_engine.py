@@ -38,6 +38,7 @@ from repro.core.tensor_engine import (
     TensorEngine,
     channel_weight_matrices,
 )
+from repro.kernels.ops import EDGE_GRANULE
 from repro.relational.relation import Database
 from repro.serve.cache import LRUCache
 
@@ -332,8 +333,9 @@ def _jit_for(key, fn) -> Callable:
 # ----------------------------------------------------------------------
 
 # edge blocks are padded to the next multiple of this count so the jitted
-# kernels see a handful of static shapes instead of one per relation
-EDGE_BUCKET = 256
+# kernels see a handful of static shapes instead of one per relation; it
+# is the kernels' 1-D edge block granule, so their own pad is a no-op
+EDGE_BUCKET = EDGE_GRANULE
 # the Pallas kernels index segments/rows in int32
 _INT32_LIMIT = 2**31
 
@@ -351,23 +353,6 @@ def _pad_edges(keys: np.ndarray, vals: np.ndarray, idx: np.ndarray | None):
     if idx is not None:
         idx = np.concatenate([idx, np.zeros(pad, np.int64)])
     return keys, vals, idx
-
-
-def _use_ref_kernels(interpret: bool | None) -> bool:
-    """``interpret=None`` (auto) on a CPU host lowers the sparse hops to
-    the pure-jnp reference kernels: the Pallas interpreter executes the
-    kernel body per grid cell in Python — a validation device, orders of
-    magnitude too slow to benchmark — while the XLA segment ops are the
-    fastest CPU lowering of the same contraction.  On TPU backends (or
-    with an explicit ``interpret`` flag) the Pallas kernels run.
-
-    Delegates to :func:`repro.kernels.ops.use_ref_kernels` so the engine
-    and the kernel wrappers share one policy: an explicit flag pins the
-    Pallas path in both places, so one program can never mix ref and
-    Pallas-interpret hops."""
-    from repro.kernels import ops
-
-    return ops.use_ref_kernels(interpret)
 
 
 # the ref spmm's per-edge gather materializes (edges × width); chunk the
@@ -467,7 +452,15 @@ class _CsrHopMixin:
                 num_segments=knum,
             )
         )
-        ops.record_dispatch("fused")
+        width = int(np.prod(child_widths, dtype=np.int64))
+        ops.record_dispatch(
+            "fused",
+            hop=dict(
+                kernel="fused_hop", edges=len(keys), segments=knum,
+                child_rows=sum(child_rows), width=width * k,
+                block_e=cfg.block_e, block_s=cfg.block_s, block_r=cfg.block_r,
+            ),
+        )
         out = ops.fused_hop(
             jnp.asarray(keys, jnp.int32),
             jnp.asarray(w32),
@@ -523,7 +516,7 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
                 w32, gathers, keys, knum, "sum", k=self.k
             )
             return out.reshape(knum, width, self.k)
-        use_ref = _use_ref_kernels(self.interpret)
+        use_ref = ops.use_ref_kernels(self.interpret)
         uniform = self.k == 1 or bool((w32 == w32[:, :1]).all())
         if len(gathers) == 1 and uniform:
             m2, idx = gathers[0]  # m2 (rows, width, k)
@@ -532,7 +525,13 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
                 flat = np.ascontiguousarray(m2, dtype=np.float32).reshape(
                     rows, width * self.k
                 )
-                ops.record_dispatch("spmm")
+                ops.record_dispatch(
+                    "spmm",
+                    hop=dict(
+                        kernel="coo_spmm", edges=ops.round_up(n, EDGE_BUCKET),
+                        segments=knum, child_rows=rows, width=width * self.k,
+                    ),
+                )
                 if use_ref:
                     out = _ref_spmm_chunked(keys, idx, w32[:, 0], flat, knum)
                 else:
@@ -565,7 +564,15 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
                     vals.shape[0], -1, self.k
                 )
             flat = vals.reshape(vals.shape[0], width * self.k)
-            ops.record_dispatch("scatter")
+            ops.record_dispatch(
+                "scatter",
+                hop=dict(
+                    kernel="segment_sum",
+                    edges=ops.round_up(len(flat), EDGE_BUCKET), segments=knum,
+                    child_rows=sum(m2.shape[0] for m2, _ in g32),
+                    width=width * self.k,
+                ),
+            )
             if use_ref:
                 part = ref.segment_sum_ref(
                     jnp.asarray(flat), jnp.asarray(keys[sl], jnp.int32), knum
@@ -628,7 +635,7 @@ class _MinMaxKernelEngine(_CsrHopMixin, TensorEngine):
             and all(m2.shape[0] < _INT32_LIMIT for m2, _ in g32)
         ):
             return self._fused_contract(w32, g32, keys, knum, self.kind)
-        use_ref = _use_ref_kernels(self.interpret)
+        use_ref = ops.use_ref_kernels(self.interpret)
         # edge axis chunked like the channel engine's general hop: the
         # per-edge candidate temp stays bounded by _REF_GATHER_BYTES
         chunk = max(1024, _REF_GATHER_BYTES // max(4 * width, 1))
@@ -642,7 +649,14 @@ class _MinMaxKernelEngine(_CsrHopMixin, TensorEngine):
                 vals = (vals[:, :, None] + rows[:, None, :]).reshape(
                     vals.shape[0], -1
                 )
-            ops.record_dispatch("scatter")
+            ops.record_dispatch(
+                "scatter",
+                hop=dict(
+                    kernel="segment_reduce",
+                    edges=ops.round_up(len(vals), EDGE_BUCKET), segments=knum,
+                    child_rows=sum(m2.shape[0] for m2, _ in g32), width=width,
+                ),
+            )
             if knum >= _INT32_LIMIT:
                 red.at(out, keys[sl], vals)
                 continue

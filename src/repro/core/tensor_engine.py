@@ -402,7 +402,7 @@ def minmax_arrays(
     er = encoded[rel_m]
     n = er.num_rows
     node = deco.nodes[rel_m]
-    reach = np.ones((n, 1))
+    gathers: list[tuple[np.ndarray, np.ndarray]] = []
     child_gattrs: list[str] = []
     for child in node.children:
         msg = eng.message(child, rel_m)
@@ -418,9 +418,9 @@ def minmax_arrays(
             np.ravel_multi_index(tuple(er.codes[:, p] for p in pos), dims=sh_dims)
             if pos else np.zeros(n, dtype=np.int64)
         )
-        rows = m2[idx]
-        reach = (reach[:, :, None] * rows[:, None, :]).reshape(n, -1)
+        gathers.append((m2, idx))
         child_gattrs.extend(msg.group_attrs)
+    width = int(np.prod([m2.shape[1] for m2, _ in gathers], dtype=np.int64))
 
     own_g = prep.schema.group_of.get(rel_m)
     kept = (own_g,) if own_g else ()
@@ -430,35 +430,47 @@ def minmax_arrays(
     else:
         keys = np.zeros(n, dtype=np.int64)
     knum = int(np.prod(kdims, dtype=np.int64)) if kdims else 1
-
-    if n:
-        order = np.argsort(keys, kind="stable")
-        ks = keys[order]
-        bounds = np.flatnonzero(np.concatenate([[True], ks[1:] != ks[:-1]]))
+    order = np.argsort(keys, kind="stable")
 
     gattrs = ([own_g] if own_g else []) + child_gattrs
     raw = list(kept) + child_gattrs
     want = sorted(gattrs, key=eng.canonical.index)
     perm = [raw.index(a) for a in want]
 
+    bads = {kind: np.inf if kind == "min" else -np.inf for kind in kinds}
+    outs = {kind: np.full((knum, width), bads[kind]) for kind in kinds}
+    # the (rows, width) reachability and candidate arrays grow with the
+    # product of the child group domains, so edges go in key-sorted
+    # chunks of bounded size; min/max are exact in any order
+    chunk = max(1, _MINMAX_CHUNK_BYTES // (8 * max(width, 1)))
+    for lo in range(0, n, chunk):
+        rows_c = order[lo : lo + chunk]
+        reach = np.ones((len(rows_c), 1))
+        for m2, idx in gathers:
+            g = m2[idx[rows_c]]
+            reach = (reach[:, :, None] * g[:, None, :]).reshape(len(rows_c), -1)
+        ks = keys[rows_c]
+        bounds = np.flatnonzero(np.concatenate([[True], ks[1:] != ks[:-1]]))
+        for kind in kinds:
+            red_fn = np.minimum if kind == "min" else np.maximum
+            m = er.payloads[kind][rows_c].astype(np.float64)
+            cand = np.where(reach > 0, m[:, None], bads[kind])
+            at = ks[bounds]  # distinct within a chunk
+            outs[kind][at] = red_fn(
+                outs[kind][at], red_fn.reduceat(cand, bounds, axis=0)
+            )
+
     out_arrs: dict[str, np.ndarray] = {}
     for kind in kinds:
-        is_min = kind == "min"
-        m = er.payloads[kind].astype(np.float64)
-        bad = np.inf if is_min else -np.inf
-        cand = np.where(reach > 0, m[:, None], bad)  # (n, G)
-        out = np.full((knum, cand.shape[1]), bad)
-        if n:
-            cs = cand[order]
-            red = (np.minimum if is_min else np.maximum).reduceat(
-                cs, bounds, axis=0
-            )
-            out[ks[bounds]] = red
-        arr = out.reshape(kdims + eng._dims(tuple(child_gattrs)))
+        arr = outs[kind].reshape(kdims + eng._dims(tuple(child_gattrs)))
         if perm != list(range(len(perm))):
             arr = np.transpose(arr, perm)
         out_arrs[kind] = np.where(np.isfinite(arr), arr, 0.0)
     return out_arrs
+
+
+# bound on the float64 reachability/candidate chunk in minmax_arrays
+_MINMAX_CHUNK_BYTES = 64 << 20
 
 
 def _minmax(query, prep, encoded, domains, offsets) -> dict[tuple, float]:

@@ -30,11 +30,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.core.jax_engine import EDGE_BUCKET
 from repro.core.tensor_engine import TensorEngine
-
-# delta blocks are padded to the next multiple of this edge count so the
-# jitted kernels see a handful of static shapes instead of one per batch
-EDGE_BUCKET = 256
 
 
 def _pad_block(

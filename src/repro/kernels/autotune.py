@@ -17,8 +17,8 @@ gather tile).  This module picks them per device:
   device tiles.
 * **on-disk cache** — measured winners persist in a JSON file keyed by
   ``<device kind>|fused_hop|<bucketed shape>`` (``REPRO_AUTOTUNE_CACHE``
-  overrides the default ``~/.cache/repro/autotune.json``), so a process
-  restart does not re-benchmark.
+  overrides the default ``<checkout>/.cache/autotune.json``), so a
+  process restart does not re-benchmark.
 
 Hop shapes bucket to powers of two (:func:`hop_shape`) so the cache and
 the jit trace count stay bounded as relation sizes drift.
@@ -34,13 +34,13 @@ from pathlib import Path
 import jax
 
 __all__ = [
-    "DEFAULT_TILES",
     "HopShape",
     "TileConfig",
     "candidate_tiles",
     "device_kind",
     "hop_shape",
     "model_tiles_for",
+    "plan_hops",
     "plan_kernel_configs",
     "tiles_for",
 ]
@@ -48,11 +48,12 @@ __all__ = [
 
 @dataclass(frozen=True)
 class TileConfig:
-    """One fused-hop tile configuration (all multiples of 8)."""
+    """One fused-hop tile configuration (``block_e`` a multiple of
+    ``ops.EDGE_GRANULE``, the others of 8)."""
 
-    block_e: int = 512
-    block_s: int = 128
-    block_r: int = 128
+    block_e: int
+    block_s: int
+    block_r: int
 
     def key(self) -> str:
         return f"e{self.block_e}.s{self.block_s}.r{self.block_r}"
@@ -85,10 +86,9 @@ class HopShape:
         )
 
 
-DEFAULT_TILES = TileConfig()
-
-#: candidate grid the model ranks; every size is a _KSTEP_GRANULE multiple
-_BLOCK_E = (256, 512, 1024)
+#: candidate grid the model ranks: edge tiles are ops.EDGE_GRANULE
+#: multiples (1-D edge operands), the others _KSTEP_GRANULE multiples
+_BLOCK_E = (1024, 2048)
 _BLOCK_S = (64, 128, 256)
 _BLOCK_R = (128, 256)
 
@@ -132,10 +132,9 @@ def hop_shape(
 
 
 def device_kind() -> str:
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:  # pragma: no cover - no backend at all
-        return "unknown"
+    """The default device's kind, as JAX reports it (the autotune cache
+    key); raises if JAX has no backend rather than inventing a kind."""
+    return jax.devices()[0].device_kind
 
 
 # ----------------------------------------------------------------------
@@ -154,11 +153,7 @@ def candidate_tiles(shape: HopShape) -> list[tuple[float, TileConfig]]:  # tile-
         for bs in _BLOCK_S:
             for br in _BLOCK_R:
                 cfg = TileConfig(be, bs, br)
-                vmem = roofline.fused_hop_vmem_bytes(
-                    be, bs, br, shape.child_rows, shape.child_widths,
-                    shape.width, shape.k,
-                )
-                if vmem > roofline.VMEM_BYTES:
+                if _vmem_bytes(shape, cfg) > roofline.VMEM_BYTES:
                     continue
                 cost = roofline.fused_hop_cost(
                     edges=shape.edges,
@@ -175,11 +170,47 @@ def candidate_tiles(shape: HopShape) -> list[tuple[float, TileConfig]]:  # tile-
     return ranked
 
 
+def _vmem_bytes(shape: HopShape, cfg: TileConfig) -> int:
+    from repro.launch import roofline
+
+    return roofline.fused_hop_vmem_bytes(
+        cfg.block_e,
+        cfg.block_s,
+        cfg.block_r,
+        shape.child_rows,
+        shape.child_widths,
+        shape.width,
+        shape.k,
+        shape.kind,
+    )
+
+
+def no_fit_reason(shape: HopShape) -> str:
+    """Why no candidate tile fits: the hop shape, the least VMEM any
+    candidate needs, and the bound."""
+    from repro.launch import roofline
+
+    least = min(
+        _vmem_bytes(shape, TileConfig(be, bs, br))
+        for be in _BLOCK_E
+        for bs in _BLOCK_S
+        for br in _BLOCK_R
+    )
+    return (
+        f"no fused-hop tile fits VMEM for hop {shape.key()}: the smallest "
+        f"candidate needs {least} B > bound {roofline.VMEM_BYTES} B "
+        "(whole child messages stay resident in VMEM)"
+    )
+
+
 def model_tiles_for(shape: HopShape) -> TileConfig:
     """Deterministic model-only choice — what ``Plan.explain()`` and the
-    verifier see; never touches the measurement cache."""
+    verifier see; never touches the measurement cache.  Raises when no
+    candidate fits VMEM."""
     ranked = candidate_tiles(shape)
-    return ranked[0][1] if ranked else DEFAULT_TILES
+    if not ranked:
+        raise ValueError(no_fit_reason(shape))
+    return ranked[0][1]
 
 
 # ----------------------------------------------------------------------
@@ -191,7 +222,9 @@ def _cache_path() -> Path:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return Path(env)
-    return Path.home() / ".cache" / "repro" / "autotune.json"
+    from repro.launch.cache import CACHE_DIR
+
+    return CACHE_DIR / "autotune.json"
 
 
 def _load_disk_cache() -> None:
@@ -282,8 +315,8 @@ def tiles_for(shape: HopShape, device: str | None = None) -> TileConfig:
         return hit
     ranked = candidate_tiles(shape)
     if not ranked:
-        cfg = DEFAULT_TILES
-    elif jax.default_backend() == "cpu":
+        raise ValueError(no_fit_reason(shape))
+    if jax.default_backend() == "cpu":
         # interpreter wall time is meaningless for device tiles — take
         # the model's pick and keep goldens/CI deterministic
         cfg = ranked[0][1]
@@ -305,17 +338,15 @@ def tiles_for(shape: HopShape, device: str | None = None) -> TileConfig:
 # ----------------------------------------------------------------------
 
 
-def plan_kernel_configs(prep, k: int = 1, kind: str = "sum") -> list[dict]:
-    """Per-hop fused-kernel configs for a prepared plan, in tree
-    post-order — the deterministic (model-only) view that
-    ``Plan.explain()`` renders and ``check_kernels`` verifies.
+def plan_hops(prep) -> list[dict]:
+    """Per-hop dimensions of a prepared plan, in tree post-order: the
+    relation, its edge count, each child message's rows and width, and
+    the hop's output segment count.
 
-    Child message rows/widths are estimated from the attribute domains:
-    a child's message rows ravel the attrs it shares with its parent,
-    its width the group attrs its subtree carries upward.
+    Child message rows/widths come from the attribute domains: a child's
+    message rows ravel the attrs it shares with its parent, its width the
+    group attrs its subtree carries upward.
     """
-    from repro.core.jax_engine import EDGE_BUCKET
-
     deco = prep.decomposition
     group_of = prep.schema.group_of
 
@@ -361,27 +392,48 @@ def plan_kernel_configs(prep, k: int = 1, kind: str = "sum") -> list[dict]:
                     width *= dim(a)
             child_rows.append(rows)
             child_widths.append(width)
-        edges = max(
-            -(-er.num_rows // EDGE_BUCKET) * EDGE_BUCKET, EDGE_BUCKET
-        )
-        shape = hop_shape(
-            edges=edges,
-            child_rows=tuple(child_rows),
-            k=k,
-            kind=kind,
-            child_widths=tuple(child_widths),
-            num_segments=knum,
-        )
-        ranked = candidate_tiles(shape)
-        cfg = ranked[0][1] if ranked else DEFAULT_TILES
         out.append(
             {
                 "rel": rel,
-                "shape": shape,
+                "edges": er.num_rows,
+                "child_rows": tuple(child_rows),
+                "child_widths": tuple(child_widths),
                 "num_segments": knum,
-                "config": cfg,
+            }
+        )
+    return out
+
+
+def plan_kernel_configs(prep, k: int = 1, kind: str = "sum") -> list[dict]:
+    """Per-hop fused-kernel configs for a prepared plan, in tree
+    post-order — the deterministic (model-only) view that
+    ``Plan.explain()`` renders and ``check_kernels`` verifies."""
+    from repro.core.jax_engine import EDGE_BUCKET
+
+    out: list[dict] = []
+    for hop in plan_hops(prep):
+        edges = max(
+            -(-hop["edges"] // EDGE_BUCKET) * EDGE_BUCKET, EDGE_BUCKET
+        )
+        shape = hop_shape(
+            edges=edges,
+            child_rows=hop["child_rows"],
+            k=k,
+            kind=kind,
+            child_widths=hop["child_widths"],
+            num_segments=hop["num_segments"],
+        )
+        ranked = candidate_tiles(shape)
+        out.append(
+            {
+                "rel": hop["rel"],
+                "shape": shape,
+                "num_segments": hop["num_segments"],
+                "config": ranked[0][1] if ranked else None,
                 "cost_seconds": ranked[0][0] if ranked else float("nan"),
                 "acc_dtype": "float32",
+                # set when no candidate fits VMEM (config is then None)
+                "no_fit": None if ranked else no_fit_reason(shape),
             }
         )
     return out

@@ -31,6 +31,9 @@ from jax.experimental.pallas import tpu as pltpu
 #: cannot race the init against another revisit.
 DIM_SEMANTICS = ("parallel", "arbitrary", "arbitrary")
 
+#: full f32 contraction: a one-hot matmul must reproduce its operand
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def _coo_spmm_kernel(
     rows_ref, cols_ref, vals_ref, dense_ref, out_ref, *, block_m: int, block_k: int
@@ -52,12 +55,16 @@ def _coo_spmm_kernel(
     # gather dense rows via one-hot matmul: (block_e, block_k) @ (block_k, n)
     iota_k = jax.lax.broadcasted_iota(jnp.int32, (rows.shape[0], block_k), 1)
     sel_k = (cols[:, None] - k0 == iota_k).astype(dtype)
-    gathered = jnp.dot(sel_k, dense_ref[...], preferred_element_type=dtype)
+    gathered = jnp.dot(
+        sel_k, dense_ref[...], preferred_element_type=dtype, precision=_EXACT
+    )
 
     m0 = mi * block_m
     iota_m = jax.lax.broadcasted_iota(jnp.int32, (block_m, rows.shape[0]), 0)
     scatter_m = (rows[None, :] - m0 == iota_m).astype(dtype) * vals[None, :]
-    out_ref[...] += jnp.dot(scatter_m, gathered, preferred_element_type=dtype)
+    out_ref[...] += jnp.dot(
+        scatter_m, gathered, preferred_element_type=dtype, precision=_EXACT
+    )
 
 
 @functools.partial(
@@ -71,7 +78,7 @@ def coo_spmm(
     dense: jax.Array,
     num_rows: int,
     block_m: int = 128,
-    block_e: int = 512,
+    block_e: int = 1024,
     block_k: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -80,7 +87,7 @@ def coo_spmm(
 
     interpret = ops.resolve_interpret(interpret)
     block_m = ops.normalize_block("block_m", block_m)
-    block_e = ops.normalize_block("block_e", block_e)
+    block_e = ops.normalize_block("block_e", block_e, ops.EDGE_GRANULE)
     block_k = ops.normalize_block("block_k", block_k)
     nnz = rows.shape[0]
     k, n = dense.shape
@@ -106,7 +113,7 @@ def coo_spmm(
         ],
         out_specs=pl.BlockSpec((block_m, n), lambda mi, ei, ki: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((m_total, n), dense.dtype),
-        compiler_params=pltpu.TPUCompilerParams(dimension_semantics=DIM_SEMANTICS),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
     )(rows.astype(jnp.int32), cols.astype(jnp.int32), vals.astype(dense.dtype), dense)
     return out[:num_rows]

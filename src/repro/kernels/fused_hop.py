@@ -22,8 +22,11 @@ Two variants share the wrapper:
   identity after the product (bit-identical to the true-gather path).
 
 Grid ``(s_tiles, e_tiles)``; the output tile is revisited across the
-edge axis and accumulated/reduced in VMEM.  Edges need no ordering —
-padding uses key ``-1`` (matches no segment) and index ``0``.
+edge axis and accumulated/reduced in VMEM.  Edge tiles are multiples of
+``ops.EDGE_GRANULE`` (the HBM tiling of 1-D arrays); the body walks each
+one in ``SUB_E``-edge sub-chunks, so its VMEM temporaries do not grow
+with the tile.  Edges need no ordering — padding uses key ``-1``
+(matches no segment) and index ``0``.
 """
 from __future__ import annotations
 
@@ -31,10 +34,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_IDENT = {"min": jnp.inf, "max": -jnp.inf}
+from repro.kernels.segment_reduce import _IDENT, minmax_scatter
+
 #: magnitudes at or above this are the ±inf identity in child messages
 _FINITE_MAX = 3.0e38
 
@@ -43,69 +48,117 @@ _FINITE_MAX = 3.0e38
 #: + accumulate/reduce, so it must be sequential ("arbitrary")
 DIM_SEMANTICS = ("parallel", "arbitrary")
 
+#: edges per pass of the kernel body: the gather, product and scatter
+#: temporaries are ``(SUB_E, width·k)``, so the VMEM a cell needs does
+#: not grow with the edge block (a multiple of ``ops.EDGE_GRANULE``)
+SUB_E = 256
 
-def _gather_sum(idx, msg, block_r, dtype):
-    """One-hot gather ``msg[idx]`` as ``block_r``-tiled MXU matmuls."""
-    block_e = idx.shape[0]
-    width_ck = msg.shape[1]
 
-    def body(ri, acc):
-        r0 = ri * block_r
-        iota_r = jax.lax.broadcasted_iota(jnp.int32, (block_e, block_r), 1)
-        sel = (idx[:, None] - r0 == iota_r).astype(dtype)
-        chunk = jax.lax.dynamic_slice_in_dim(msg, r0, block_r, axis=0)
-        return acc + jnp.dot(sel, chunk, preferred_element_type=dtype)
-
-    # exact: the wrapper pads child rows to a block_r multiple
-    steps = msg.shape[0] // block_r  # lint-ok: tile-floordiv
-    return jax.lax.fori_loop(
-        0, steps, body, jnp.zeros((block_e, width_ck), dtype)
+def _dot(a, b):
+    # full f32 contraction: every one-hot / 0-1 expansion matmul here must
+    # reproduce its selected operand exactly
+    return jnp.dot(
+        a,
+        b,
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
-def _gather_minmax(idx, msg, block_r, dtype):
+def _gather_sum(idx, msg_ref, block_r):
+    """One-hot gather ``msg[idx]`` as ``block_r``-tiled MXU matmuls; each
+    row tile is read from the message ref."""
+    block_e = idx.shape[0]
+    width_ck = msg_ref.shape[1]
+
+    def body(ri, acc):
+        r0 = pl.multiple_of(ri * block_r, block_r)
+        iota_r = jax.lax.broadcasted_iota(jnp.int32, (block_e, block_r), 1)
+        sel = (idx[:, None] - r0 == iota_r).astype(jnp.float32)
+        return acc + _dot(sel, msg_ref[pl.ds(r0, block_r), :])
+
+    # exact: the wrapper pads child rows to a block_r multiple
+    steps = msg_ref.shape[0] // block_r  # lint-ok: tile-floordiv
+    return jax.lax.fori_loop(
+        0, steps, body, jnp.zeros((block_e, width_ck), jnp.float32)
+    )
+
+
+def _gather_minmax(idx, msg_ref, block_r):
     """Like :func:`_gather_sum`, but ±inf identity entries gather as 0
     with a parallel 0/1 finiteness mask (a one-hot matmul against ±inf
     would produce nan)."""
     block_e = idx.shape[0]
-    width_ck = msg.shape[1]
+    width_ck = msg_ref.shape[1]
 
     def body(ri, carry):
         acc, fin = carry
-        r0 = ri * block_r
+        r0 = pl.multiple_of(ri * block_r, block_r)
         iota_r = jax.lax.broadcasted_iota(jnp.int32, (block_e, block_r), 1)
-        sel = (idx[:, None] - r0 == iota_r).astype(dtype)
-        chunk = jax.lax.dynamic_slice_in_dim(msg, r0, block_r, axis=0)
+        sel = (idx[:, None] - r0 == iota_r).astype(jnp.float32)
+        chunk = msg_ref[pl.ds(r0, block_r), :]
         finite = (chunk > -_FINITE_MAX) & (chunk < _FINITE_MAX)
-        vals = jnp.where(finite, chunk, 0.0).astype(dtype)
+        vals = jnp.where(finite, chunk, 0.0)
         return (
-            acc + jnp.dot(sel, vals, preferred_element_type=dtype),
-            fin + jnp.dot(sel, finite.astype(dtype), preferred_element_type=dtype),
+            acc + _dot(sel, vals),
+            fin + _dot(sel, finite.astype(jnp.float32)),
         )
 
     # exact: the wrapper pads child rows to a block_r multiple
-    steps = msg.shape[0] // block_r  # lint-ok: tile-floordiv
-    zero = jnp.zeros((block_e, width_ck), dtype)
+    steps = msg_ref.shape[0] // block_r  # lint-ok: tile-floordiv
+    zero = jnp.zeros((block_e, width_ck), jnp.float32)
     return jax.lax.fori_loop(0, steps, body, (zero, zero))
+
+
+def _product_plan(widths: tuple[int, ...], k: int):
+    """Static plan for the per-edge channel-diagonal product.
+
+    With running product ``P`` (width ``wa``, layout width-major/k-minor)
+    and gathered child rows ``G`` (width ``wc``), the next product is
+    ``P'[(a·wc + c)·k + j] = P[a·k + j] ⊗ G[c·k + j]``.  Mosaic cannot
+    reshape a lane axis into ``(width, k)``, so both sides are widened by
+    0/1 expansion matmuls instead — ``P @ rep`` and ``G @ til`` — and
+    combined elementwise.  An expansion that would be the identity, or a
+    lane broadcast of a single column, is skipped.  Returns the per-child
+    ``(rep_dot, til_dot)`` flags and the matrices in kernel-input order.
+    """
+    plan, mats = [], []
+    wa = 1
+    for wc in widths:
+        q = np.arange(wa * wc * k)
+        a, rem = np.divmod(q, wc * k)
+        c, j = np.divmod(rem, k)
+        rep_dot = wc > 1 and wa * k > 1
+        til_dot = wa > 1
+        if rep_dot:
+            mats.append(np.arange(wa * k)[:, None] == (a * k + j)[None, :])
+        if til_dot:
+            mats.append(np.arange(wc * k)[:, None] == (c * k + j)[None, :])
+        plan.append((rep_dot, til_dot))
+        wa *= wc
+    return tuple(plan), [m.astype(np.float32) for m in mats]
 
 
 def _fused_hop_kernel(
     *refs,
-    widths: tuple[int, ...],
-    k: int,
+    plan: tuple[tuple[bool, bool], ...],
     block_s: int,
     block_r: int,
     kind: str,
     k_step: int,
 ):
-    nchild = len(widths)
+    nchild = len(plan)
     keys_ref, w_ref = refs[0], refs[1]
     idx_refs = refs[2 : 2 + nchild]
     msg_refs = refs[2 + nchild : 2 + 2 * nchild]
-    out_ref = refs[2 + 2 * nchild]
+    rest = iter(refs[2 + 2 * nchild :])
+    exp_refs = [
+        (next(rest) if rep_dot else None, next(rest) if til_dot else None)
+        for rep_dot, til_dot in plan
+    ]
+    out_ref = next(rest)
     si = pl.program_id(0)
     ei = pl.program_id(1)
-    dtype = out_ref.dtype
 
     @pl.when(ei == 0)
     def _init():
@@ -114,50 +167,56 @@ def _fused_hop_kernel(
         else:
             out_ref[...] = jnp.full_like(out_ref, _IDENT[kind])
 
-    keys = keys_ref[...]  # (block_e,) int32 (global segment ids)
-    block_e = keys.shape[0]
-    seg0 = si * block_s
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (block_s, block_e), 0)
+    def widen(p, g, *, rep_ref, til_ref):
+        if rep_ref is not None:
+            p = _dot(p, rep_ref[...])
+        if til_ref is not None:
+            g = _dot(g, til_ref[...])
+        return p, g
 
+    block_e = keys_ref.shape[0]
+    sub_e = min(block_e, SUB_E)
+    seg0 = si * block_s
+
+    # the edge tile is walked in static sub-chunks of sub_e edges, so the
+    # (edges, width·k) gather/product temporaries are sub_e rows deep
+    # however large the HBM edge block is
     if kind == "sum":
-        acc = w_ref[...][:, None, :]  # (block_e, 1, k)
-        for idx_ref, msg_ref, wc in zip(idx_refs, msg_refs, widths):
-            g = _gather_sum(idx_ref[...], msg_ref[...], block_r, dtype)
-            gr = g.reshape(block_e, wc, k)
-            # channel-diagonal product, width-major/k-minor like the
-            # three-dispatch engine's host-side product
-            acc = (acc[:, :, None, :] * gr[:, None, :, :]).reshape(
-                block_e, -1, k
-            )
-        flat = acc.reshape(block_e, -1)  # (block_e, width·k)
-        onehot = (keys[None, :] - seg0 == iota_s).astype(dtype)
-        out_ref[...] += jnp.dot(onehot, flat, preferred_element_type=dtype)
+        for c0 in range(0, block_e, sub_e):
+            rows = pl.ds(c0, sub_e)
+            acc = w_ref[rows, :]  # (sub_e, k)
+            for idx_ref, msg_ref, (rep_ref, til_ref) in zip(
+                idx_refs, msg_refs, exp_refs
+            ):
+                g = _gather_sum(idx_ref[rows], msg_ref, block_r)
+                p, g = widen(acc, g, rep_ref=rep_ref, til_ref=til_ref)
+                acc = p * g
+            keys = keys_ref[rows]  # (sub_e,) int32 (global segment ids)
+            iota_s = jax.lax.broadcasted_iota(jnp.int32, (block_s, sub_e), 0)
+            onehot = (keys[None, :] - seg0 == iota_s).astype(jnp.float32)
+            out_ref[...] += _dot(onehot, acc)  # acc: (sub_e, width·k)
         return
 
-    # min/max: additive product with finiteness tracking
-    ident = _IDENT[kind]
-    acc = w_ref[...]  # (block_e, 1)
-    ok = jnp.ones_like(acc)
-    for idx_ref, msg_ref, _wc in zip(idx_refs, msg_refs, widths):
-        g, fin = _gather_minmax(idx_ref[...], msg_ref[...], block_r, dtype)
-        acc = (acc[:, :, None] + g[:, None, :]).reshape(block_e, -1)
-        ok = (ok[:, :, None] * fin[:, None, :]).reshape(block_e, -1)
-    cand = jnp.where(ok > 0.5, acc, ident)  # (block_e, width)
-    sel = keys[None, :] - seg0 == iota_s
-    a = jnp.where(sel, 0.0, ident).astype(dtype)
-    red = jnp.minimum if kind == "min" else jnp.maximum
-
-    def body(i, accum):
-        lo = i * k_step
-        a_sl = jax.lax.dynamic_slice_in_dim(a, lo, k_step, axis=1)
-        d_sl = jax.lax.dynamic_slice_in_dim(cand, lo, k_step, axis=0)
-        c = a_sl[:, :, None] + d_sl[None, :, :]
-        upd = jnp.min(c, axis=1) if kind == "min" else jnp.max(c, axis=1)
-        return red(accum, upd)
-
-    # exact: block_e is a normalized block, so k_step divides it
-    steps = block_e // k_step  # lint-ok: tile-floordiv
-    out_ref[...] = jax.lax.fori_loop(0, steps, body, out_ref[...])
+    # min/max: additive product with finiteness tracking; the candidates
+    # go through a VMEM scratch ref so the k-slice scatter reads its
+    # windows from a ref, exactly like segment_reduce
+    cand_ref, kcol_ref = next(rest), next(rest)
+    for c0 in range(0, block_e, sub_e):
+        rows = pl.ds(c0, sub_e)
+        acc = w_ref[rows, :]  # (sub_e, 1)
+        ok = jnp.ones_like(acc)
+        for idx_ref, msg_ref, (rep_ref, til_ref) in zip(
+            idx_refs, msg_refs, exp_refs
+        ):
+            g, fin = _gather_minmax(idx_ref[rows], msg_ref, block_r)
+            p, g = widen(acc, g, rep_ref=rep_ref, til_ref=til_ref)
+            acc = p + g
+            p, fin = widen(ok, fin, rep_ref=rep_ref, til_ref=til_ref)
+            ok = p * fin
+        cand_ref[rows, :] = jnp.where(ok > 0.5, acc, _IDENT[kind])
+    minmax_scatter(
+        keys_ref, kcol_ref, cand_ref, out_ref, seg0, kind=kind, k_step=k_step
+    )
 
 
 @functools.partial(
@@ -180,7 +239,7 @@ def fused_hop(
     num_segments: int,
     k: int = 1,
     kind: str = "sum",
-    block_e: int = 512,
+    block_e: int = 1024,
     block_s: int = 128,
     block_r: int = 128,
     interpret: bool | None = None,
@@ -198,7 +257,7 @@ def fused_hop(
     from repro.kernels import ops
 
     interpret = ops.resolve_interpret(interpret)
-    block_e = ops.normalize_block("block_e", block_e)
+    block_e = ops.normalize_block("block_e", block_e, ops.EDGE_GRANULE)
     block_s = ops.normalize_block("block_s", block_s)
     block_r = ops.normalize_block("block_r", block_r)
     if kind not in ("sum", "min", "max"):
@@ -264,23 +323,32 @@ def fused_hop(
     grid = (s_total // block_s, e_total // block_e)
     out_width = max(width * k, 1)
 
+    plan, exp_mats = _product_plan(tuple(widths), k)
     e_spec = pl.BlockSpec((block_e,), lambda si, ei: (ei,))
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda si, ei: (0, 0))
+
     in_specs = [
         e_spec,
         pl.BlockSpec((block_e, k), lambda si, ei: (ei, 0)),
         *[e_spec for _ in idxs],
-        # whole child messages are resident per grid cell; the autotuner
-        # keeps candidate tiles within the VMEM budget
-        *[
-            pl.BlockSpec(m.shape, lambda si, ei: (0, 0))
-            for m in msgs
-        ],
+        # whole child messages (and the product's expansion matrices) are
+        # resident per grid cell; the autotuner keeps candidate tiles
+        # within the VMEM budget
+        *[whole(m) for m in msgs],
+        *[whole(m) for m in exp_mats],
     ]
+    scratch = []
+    if kind != "sum":
+        scratch = [
+            pltpu.VMEM((block_e, out_width), f32),
+            pltpu.VMEM((block_e, 1), jnp.int32),
+        ]
     out = pl.pallas_call(
         functools.partial(
             _fused_hop_kernel,
-            widths=tuple(widths),
-            k=k,
+            plan=plan,
             block_s=block_s,
             block_r=block_r,
             kind=kind,
@@ -290,7 +358,8 @@ def fused_hop(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_s, out_width), lambda si, ei: (si, 0)),
         out_shape=jax.ShapeDtypeStruct((s_total, out_width), f32),
-        compiler_params=pltpu.TPUCompilerParams(dimension_semantics=DIM_SEMANTICS),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
-    )(keys, weights, *idxs, *msgs)
+    )(keys, weights, *idxs, *msgs, *(jnp.asarray(m) for m in exp_mats))
     return out[:num_segments]

@@ -3,25 +3,29 @@
 This module is the single home for the cross-kernel decisions that used
 to be duplicated (and could disagree) per kernel file:
 
-* **interpret resolution** — ``resolve_interpret`` maps the
-  ``interpret=None`` auto mode to "interpret on CPU hosts" exactly once,
-  and pins an *explicit* flag to the Pallas path: ``interpret=False`` on
-  a CPU host still runs Pallas (in interpret mode, since CPU has no
-  Mosaic target) rather than silently mixing Pallas kernels with the
-  jnp reference kernels inside one program.  ``use_ref_kernels`` is the
-  engine-side twin: the jnp reference kernels are only ever substituted
-  in the fully-automatic ``interpret=None`` mode.
+* **interpret resolution** — ``resolve_interpret`` is the one place
+  a kernel's ``interpret`` flag becomes concrete.  ``interpret=False``
+  means "compiled by Mosaic" and raises on a CPU backend (which has no
+  Mosaic target) instead of quietly running the interpreter;
+  ``interpret=True`` is the CPU test path; ``None`` (auto) compiles on
+  an accelerator backend.  ``use_ref_kernels`` is the engine-side twin:
+  the jnp reference kernels are only ever substituted in auto mode on a
+  CPU backend, so one program never mixes ref and Pallas hops and an
+  accelerator never runs the references.
 * **block normalization** — ``normalize_block`` rounds requested block
   sizes up to the k-step granule (8) so ``k_step = gcd(block, 8)`` can
   never silently degrade to a 1-wide scalar-slice ``fori_loop``;
   ``k_step_for`` raises instead of degrading if handed an
-  un-normalized block.
+  un-normalized block.  Blocks over 1-D edge arrays round up to
+  ``EDGE_GRANULE`` instead (see there).
 * **fused-path switch** — ``fused_enabled`` resolves the per-plan
   option against the ``REPRO_FUSED`` environment default.
 * **dispatch accounting** — host-side counters
   (``record_dispatch``/``dispatch_counts``) that the engines bump per
   emitted kernel launch; benchmark table 15 uses them as the
   CPU-measurable proxy for the fused path's 3-dispatches→1 reduction.
+  Hop kernel calls also leave their shapes (``dispatched_hops``), which
+  the chip smoke prints.
 
 The kernel modules import this policy lazily (inside their wrapper
 bodies) and this module re-exports the kernels at the bottom, so either
@@ -29,6 +33,7 @@ import order works without a cycle.
 """
 from __future__ import annotations
 
+import collections
 import math
 import os
 import threading
@@ -39,6 +44,12 @@ import jax
 #: semiring_matmul / fused min-max hops; blocks are rounded up to a
 #: multiple of this so ``gcd(block, _KSTEP_GRANULE)`` is always exact
 _KSTEP_GRANULE = 8
+
+#: XLA lays a 1-D int32/float32 array out in HBM as ``T(1024)`` tiles;
+#: Mosaic refuses a 1-D block whose own tiling (``T(block)`` up to 1024)
+#: does not match, so every block over a 1-D edge operand (keys, ids,
+#: values) is a multiple of this
+EDGE_GRANULE = 1024
 
 _TRUTHY = frozenset({"1", "true", "on", "yes"})
 
@@ -51,23 +62,28 @@ _TRUTHY = frozenset({"1", "true", "on", "yes"})
 def resolve_interpret(interpret: bool | None) -> bool:
     """Resolve a kernel ``interpret`` flag to a concrete bool.
 
-    ``None`` (auto) → interpret on CPU hosts, compiled elsewhere.  An
-    explicit ``False`` on a CPU host degrades to ``True`` — CPU has no
-    Mosaic lowering, and the contract of an explicit flag is "run the
-    Pallas kernel path", never "fall back to something else".
+    ``None`` (auto) → interpret on a CPU backend, Mosaic-compiled
+    elsewhere.  An explicit ``False`` is a request for the compiled
+    kernel: on a CPU backend it raises rather than degrading to the
+    interpreter, so a run that asked for the chip cannot silently
+    measure something else.
     """
+    on_cpu = jax.default_backend() == "cpu"
     if interpret is None:
-        return jax.default_backend() == "cpu"
-    if not interpret and jax.default_backend() == "cpu":
-        return True
+        return on_cpu
+    if not interpret and on_cpu:
+        raise RuntimeError(
+            "interpret=False asks for Mosaic-compiled Pallas kernels, but "
+            "the JAX backend is cpu; pass interpret=True to run the "
+            "Pallas interpreter"
+        )
     return bool(interpret)
 
 
 def use_ref_kernels(interpret: bool | None) -> bool:
     """True when the engines should run jnp reference kernels instead of
-    Pallas.  Only the fully-automatic mode ever substitutes refs: an
-    explicit ``interpret=True``/``False`` pins the Pallas path so a
-    single program can't mix ref and Pallas-interpret kernels."""
+    Pallas: auto mode on a CPU backend only.  An explicit flag pins the
+    Pallas path, so a single program can't mix ref and Pallas hops."""
     return interpret is None and jax.default_backend() == "cpu"
 
 
@@ -94,16 +110,18 @@ def round_up(value: int, multiple: int) -> int:
     return -(-value // multiple) * multiple
 
 
-def normalize_block(name: str, value: int) -> int:
-    """Validate and round a block size up to the k-step granule.
+def normalize_block(name: str, value: int, granule: int = _KSTEP_GRANULE) -> int:
+    """Validate and round a block size up to ``granule`` (the k-step
+    granule, or :data:`EDGE_GRANULE` for blocks over 1-D edge arrays).
 
     Tiling is semantics-free (the wrappers pad inputs to the block
     grid), so rounding up never changes results — it only prevents
     ``gcd(block, 8) == 1`` from quietly turning the reduction loop into
-    a per-row scalar slice."""
+    a per-row scalar slice, and 1-D edge blocks from taking a tiling the
+    TPU compiler refuses."""
     if not isinstance(value, int) or isinstance(value, bool) or value <= 0:
         raise ValueError(f"{name} must be a positive int, got {value!r}")
-    return round_up(value, _KSTEP_GRANULE)
+    return round_up(value, granule)
 
 
 def k_step_for(block: int) -> int:
@@ -124,13 +142,20 @@ def k_step_for(block: int) -> int:
 
 _dispatch_lock = threading.Lock()
 _dispatch_counts: dict[str, int] = {}
+#: the newest kernel dispatches' shapes (bounded: a server records forever)
+_dispatch_hops: collections.deque[dict] = collections.deque(maxlen=256)
 
 
-def record_dispatch(stage: str, n: int = 1) -> None:
+def record_dispatch(stage: str, n: int = 1, hop: dict | None = None) -> None:
     """Count ``n`` kernel dispatches attributed to ``stage`` (one of
-    ``gather``/``product``/``scatter``/``fused``)."""
+    ``spmm``/``gather``/``product``/``scatter``/``fused``).  ``hop``, where
+    given, describes one hop kernel call — ``kernel``, ``edges`` (as the
+    engine passes them), ``segments``, ``child_rows``, ``width`` (output
+    columns) and any non-default blocks — for :func:`dispatched_hops`."""
     with _dispatch_lock:
         _dispatch_counts[stage] = _dispatch_counts.get(stage, 0) + n
+        if hop is not None:
+            _dispatch_hops.append(hop)
 
 
 def dispatch_counts() -> dict[str, int]:
@@ -138,9 +163,16 @@ def dispatch_counts() -> dict[str, int]:
         return dict(_dispatch_counts)
 
 
+def dispatched_hops() -> list[dict]:
+    """Shapes of the hop kernel calls since the last reset, oldest first."""
+    with _dispatch_lock:
+        return list(_dispatch_hops)
+
+
 def reset_dispatch_counts() -> None:
     with _dispatch_lock:
         _dispatch_counts.clear()
+        _dispatch_hops.clear()
 
 
 # re-exports: ops is the stable import surface for all kernels; these
@@ -154,7 +186,9 @@ from repro.kernels.semiring_matmul import semiring_matmul  # noqa: E402
 
 __all__ = [
     "coo_spmm",
+    "EDGE_GRANULE",
     "dispatch_counts",
+    "dispatched_hops",
     "fused_enabled",
     "fused_hop",
     "k_step_for",

@@ -33,39 +33,57 @@ _IDENT = {"min": jnp.inf, "max": -jnp.inf}
 DIM_SEMANTICS = ("parallel", "arbitrary")
 
 
+def minmax_scatter(
+    keys_ref, kcol_ref, rows_ref, out_ref, seg0, *, kind: str, k_step: int
+):
+    """Reduce the rows of ``rows_ref`` (n, d) into the resident output
+    tile ``out_ref`` (block_s, d) with min/max: row ``r`` lands in tile
+    row ``keys[r] - seg0``; keys outside the tile contribute the
+    identity.
+
+    ``k_step`` rows at a time, each window read from a ref (Mosaic has
+    no in-kernel ``dynamic_slice`` of a value).  The 1-D keys are first
+    staged as a ``(n, 1)`` column in the VMEM scratch ``kcol_ref``, so a
+    key window is a sublane slice; the selector window
+    ``A[s, j] = 0 if keys[lo + j] == seg0 + s else ±inf`` then broadcasts
+    along lanes, and the window's candidates
+    ``reduce_j (A[s, j] + rows[lo + j, :])`` reduce on the VPU.  Shared
+    by ``segment_reduce`` and the min/max ``fused_hop``."""
+    ident = _IDENT[kind]
+    block_s = out_ref.shape[0]
+    kcol_ref[...] = keys_ref[...][:, None]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (block_s, k_step, 1), 0)
+    red = jnp.minimum if kind == "min" else jnp.maximum
+    red_axis = jnp.min if kind == "min" else jnp.max
+
+    def body(i, acc):
+        lo = pl.multiple_of(i * k_step, k_step)
+        ids = kcol_ref[pl.ds(lo, k_step), :]  # (k_step, 1)
+        a_sl = jnp.where(ids[None, :, :] - seg0 == iota, 0.0, ident).astype(
+            acc.dtype
+        )
+        d_sl = rows_ref[pl.ds(lo, k_step), :]
+        return red(acc, red_axis(a_sl + d_sl[None, :, :], axis=1))
+
+    # exact: the wrappers pick k_step = gcd(block, 8), so it divides the
+    # block row count by construction
+    steps = rows_ref.shape[0] // k_step  # lint-ok: tile-floordiv
+    out_ref[...] = jax.lax.fori_loop(0, steps, body, out_ref[...])
+
+
 def _segment_reduce_kernel(
-    ids_ref, data_ref, out_ref, *, block_s: int, kind: str, k_step: int
+    ids_ref, data_ref, out_ref, kcol_ref, *, block_s: int, kind: str, k_step: int
 ):
     si = pl.program_id(0)
     rj = pl.program_id(1)
-    ident = _IDENT[kind]
 
     @pl.when(rj == 0)
     def _init():
-        out_ref[...] = jnp.full_like(out_ref, ident)
+        out_ref[...] = jnp.full_like(out_ref, _IDENT[kind])
 
-    ids = ids_ref[...]  # (block_n,) int32 (global segment ids)
-    seg0 = si * block_s
-    # A[s, r] = 0 iff ids[r] == seg0 + s else ±inf  -> (block_s, block_n)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (block_s, ids.shape[0]), 0)
-    sel = ids[None, :] - seg0 == iota
-    a = jnp.where(sel, 0.0, ident).astype(out_ref.dtype)
-    data = data_ref[...]
-    red = jnp.minimum if kind == "min" else jnp.maximum
-
-    def body(i, acc):
-        lo = i * k_step
-        a_sl = jax.lax.dynamic_slice_in_dim(a, lo, k_step, axis=1)
-        d_sl = jax.lax.dynamic_slice_in_dim(data, lo, k_step, axis=0)
-        cand = a_sl[:, :, None] + d_sl[None, :, :]
-        upd = jnp.min(cand, axis=1) if kind == "min" else jnp.max(cand, axis=1)
-        return red(acc, upd)
-
-    # exact: the wrapper picks k_step = gcd(block_n, 8), so it divides
-    # the block row count by construction
-    steps = ids.shape[0] // k_step  # lint-ok: tile-floordiv
-    acc = jax.lax.fori_loop(0, steps, body, out_ref[...])
-    out_ref[...] = acc
+    minmax_scatter(
+        ids_ref, kcol_ref, data_ref, out_ref, si * block_s, kind=kind, k_step=k_step
+    )
 
 
 @functools.partial(
@@ -78,7 +96,7 @@ def segment_reduce(
     num_segments: int,
     kind: str = "min",
     block_s: int = 128,
-    block_n: int = 512,
+    block_n: int = 1024,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Reduce rows of ``data`` (n, d) into ``num_segments`` buckets with
@@ -90,7 +108,7 @@ def segment_reduce(
 
     interpret = ops.resolve_interpret(interpret)
     block_s = ops.normalize_block("block_s", block_s)
-    block_n = ops.normalize_block("block_n", block_n)
+    block_n = ops.normalize_block("block_n", block_n, ops.EDGE_GRANULE)
     if kind not in _IDENT:
         raise ValueError(f"unknown reduction {kind!r}")
     n, d = data.shape
@@ -116,7 +134,8 @@ def segment_reduce(
         ],
         out_specs=pl.BlockSpec((block_s, d), lambda si, rj: (si, 0)),
         out_shape=jax.ShapeDtypeStruct((s_total, d), data.dtype),
-        compiler_params=pltpu.TPUCompilerParams(dimension_semantics=DIM_SEMANTICS),
+        scratch_shapes=[pltpu.VMEM((block_n, 1), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
     )(segment_ids.astype(jnp.int32), data)
     return out[:num_segments]

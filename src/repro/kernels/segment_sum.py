@@ -38,7 +38,10 @@ def _segment_sum_kernel(ids_ref, data_ref, out_ref, *, block_s: int):
     iota = jax.lax.broadcasted_iota(jnp.int32, (block_s, ids.shape[0]), 0)
     onehot = (ids[None, :] - seg0 == iota).astype(data_ref.dtype)
     out_ref[...] += jnp.dot(
-        onehot, data_ref[...], preferred_element_type=out_ref.dtype
+        onehot,
+        data_ref[...],
+        preferred_element_type=out_ref.dtype,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -50,7 +53,7 @@ def segment_sum(
     segment_ids: jax.Array,
     num_segments: int,
     block_s: int = 128,
-    block_n: int = 512,
+    block_n: int = 1024,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Sum rows of ``data`` (n, d) into ``num_segments`` buckets.
@@ -61,7 +64,7 @@ def segment_sum(
 
     interpret = ops.resolve_interpret(interpret)
     block_s = ops.normalize_block("block_s", block_s)
-    block_n = ops.normalize_block("block_n", block_n)
+    block_n = ops.normalize_block("block_n", block_n, ops.EDGE_GRANULE)
     n, d = data.shape
     n_pad = -n % block_n
     s_pad = -num_segments % block_s
@@ -80,7 +83,7 @@ def segment_sum(
         ],
         out_specs=pl.BlockSpec((block_s, d), lambda si, rj: (si, 0)),
         out_shape=jax.ShapeDtypeStruct((s_total, d), data.dtype),
-        compiler_params=pltpu.TPUCompilerParams(dimension_semantics=DIM_SEMANTICS),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
     )(segment_ids.astype(jnp.int32), data)
     return out[:num_segments]

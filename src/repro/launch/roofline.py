@@ -129,6 +129,12 @@ def analyze_cell(rec: dict) -> dict | None:
 VMEM_BYTES = 16 * 2**20
 
 
+def _vmem_tile(rows: int, cols: int) -> int:  # tile-math
+    """Bytes of a 32-bit ``(rows, cols)`` array in VMEM, padded to the
+    ``(8, 128)`` sublane × lane tiling."""
+    return 4 * (-(-max(rows, 1) // 8) * 8) * (-(-max(cols, 1) // 128) * 128)
+
+
 def fused_hop_vmem_bytes(  # tile-math
     block_e: int,
     block_s: int,
@@ -137,18 +143,51 @@ def fused_hop_vmem_bytes(  # tile-math
     child_widths: tuple[int, ...],
     width: int,
     k: int,
+    kind: str = "sum",
 ) -> int:
-    """f32 bytes resident in one fused-hop grid cell: the whole child
-    messages (full-array BlockSpecs), the edge tile's key/weight/index
-    columns, the gather selector + per-child gathered tile, the
-    ``(block_e, width·k)`` product, and the output tile."""
+    """Bytes of VMEM one fused-hop grid cell holds, padded to the
+    ``(8, 128)`` tiling.
+
+    Pipelined windows count twice (double-buffered): the edge tile's
+    1-D key/index columns and ``(block_e, k)`` weights, and the output
+    tile.  Whole-array windows — the child messages and the product's
+    expansion matrices — are single-buffered and count once.  Body
+    temporaries count once, at the kernel's ``SUB_E``-edge sub-chunk:
+    the gather selector and its iota, each child's gathered tile (twice
+    for min/max, which carries a finiteness mask), the widened product
+    operands, the scatter selector and its iota; for min/max add the
+    whole-tile candidate and key-column scratch plus one
+    ``(block_s, 8, width)`` window.  Checked against what the TPU
+    compiler allocates for a described v5e: on the tested shapes it
+    over-counts, never under-counts."""
     rows_pad = [max(-(-r // block_r) * block_r, block_r) for r in child_rows]
-    msgs = sum(r * wc * k for r, wc in zip(rows_pad, child_widths))
-    edge_cols = block_e * (2 + len(child_rows) + k)  # keys+w+idx columns
-    gather = block_e * block_r + sum(block_e * wc * k for wc in child_widths)
-    product = block_e * width * k
-    out_tile = block_s * width * k + block_s * block_e  # + scatter selector
-    return 4 * (msgs + edge_cols + gather + product + out_tile)
+    out_w = max(width * k, 1)
+    piped = 4 * block_e * (1 + len(child_rows)) + _vmem_tile(block_e, k)
+    piped += _vmem_tile(block_s, out_w)
+    resident = sum(
+        _vmem_tile(r, wc * k) for r, wc in zip(rows_pad, child_widths)
+    )
+    wa = 1
+    for wc in child_widths:  # expansion matrices (see fused_hop)
+        if wc > 1 and wa * k > 1:
+            resident += _vmem_tile(wa * k, wa * wc * k)
+        if wa > 1:
+            resident += _vmem_tile(wc * k, wa * wc * k)
+        wa *= wc
+    minmax = kind != "sum"
+    from repro.kernels.fused_hop import SUB_E
+
+    sub_e = min(block_e, SUB_E)
+    temps = 2 * _vmem_tile(sub_e, block_r) + 2 * _vmem_tile(block_s, sub_e)
+    temps += sum(
+        (2 if minmax else 1) * _vmem_tile(sub_e, wc * k)
+        for wc in child_widths
+    )
+    temps += 3 * _vmem_tile(sub_e, out_w)
+    if minmax:
+        temps += _vmem_tile(block_e, out_w) + _vmem_tile(block_e, 1)
+        temps += 2 * block_s * _vmem_tile(8, out_w)
+    return 2 * piped + resident + temps
 
 
 def fused_hop_cost(  # tile-math
@@ -157,7 +196,7 @@ def fused_hop_cost(  # tile-math
     child_widths: tuple[int, ...],
     num_segments: int,
     k: int = 1,
-    block_e: int = 512,
+    block_e: int = 1024,
     block_s: int = 128,
     block_r: int = 128,
 ) -> dict[str, float]:

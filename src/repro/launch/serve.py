@@ -8,19 +8,22 @@ then talk to it with :func:`repro.serve.session.connect`, or over raw
 newline-delimited JSON (see :mod:`repro.serve.wire`).  The demo database
 is the paper's C1 chain R1(g1,p0) ⋈ R2(p0,p1) ⋈ R3(p1,p2) ⋈ R4(p2,g2)
 with a ``w`` measure column on R2 so SUM/AVG/MIN/MAX queries work out of
-the box.
+the box.  The demo queries run on ``--engine`` (default ``jax``, the
+engine whose hot path runs on the accelerator).
 
 ``--smoke`` runs the CI gate instead of serving forever: it starts the
 server, fires concurrent mixed-shape clients at it — repeated shapes
 exercising the warm plan cache and the fusion batcher, a maintained view
 read under writes — and exits non-zero unless every result is
-bit-identical to a single-shot ``Plan.execute()`` oracle.
+bit-identical to a single-shot ``Plan.execute()`` of the same query on
+the ``tensor`` engine (numpy), the oracle.
 """
 from __future__ import annotations
 
 import argparse
 import sys
 import threading
+from dataclasses import replace
 
 import numpy as np
 
@@ -28,6 +31,7 @@ from repro.aggregates.semiring import Avg, Count, Max, Min, Sum
 from repro.api.builder import Q
 from repro.api.plan import compile_plan
 from repro.data.synth import chain
+from repro.launch.cache import enable_compile_cache
 from repro.relational.relation import Database
 from repro.serve.server import JoinAggServer, serve_tcp
 from repro.serve.session import connect
@@ -42,9 +46,9 @@ def demo_database(scale: int, seed: int = 0) -> Database:
     return db
 
 
-def demo_queries() -> dict[str, Q]:
+def demo_queries(engine: str = "jax") -> dict[str, Q]:
     """The mixed query shapes the smoke clients rotate through."""
-    base = Q.over("R1", "R2", "R3", "R4")
+    base = Q.over("R1", "R2", "R3", "R4").engine(engine)
     return {
         "count": base.group_by("R1.g1").agg(n=Count()),
         "sum": base.group_by("R1.g1").agg(total=Sum("R2.w")),
@@ -60,9 +64,10 @@ def demo_queries() -> dict[str, Q]:
 
 def run_smoke(args) -> int:
     db = demo_database(args.scale, seed=0)
-    queries = demo_queries()
+    queries = demo_queries(args.engine)
     oracles = {
-        name: compile_plan(q, db).execute() for name, q in queries.items()
+        name: compile_plan(replace(q, engine_name="tensor"), db).execute()
+        for name, q in queries.items()
     }
 
     srv = JoinAggServer(
@@ -139,6 +144,7 @@ def run_smoke(args) -> int:
                 "relations": ["R1", "R2", "R3", "R4"],
                 "group_by": ["R1.g1"],
                 "aggs": {"n": {"kind": "count"}},
+                "engine": args.engine,
             }
         )
         check("count", rres)
@@ -187,6 +193,8 @@ def main() -> None:
                     help="prepared-plan cache capacity")
     ap.add_argument("--scale", type=int, default=5000,
                     help="rows per relation in the demo chain database")
+    ap.add_argument("--engine", default="jax",
+                    help="engine the demo queries run on")
     ap.add_argument("--smoke", action="store_true",
                     help="run the concurrent-correctness gate and exit")
     ap.add_argument("--clients", type=int, default=8,
@@ -195,6 +203,7 @@ def main() -> None:
     ap.add_argument("--view-batches", type=int, default=6,
                     help="(smoke) delta batches applied to the view")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         sys.exit(run_smoke(args))
@@ -207,12 +216,13 @@ def main() -> None:
         fusion_window=args.fusion_window,
     )
     core.create_view(
-        "by_g1", demo_queries()["count"]
+        "by_g1", demo_queries(args.engine)["count"]
     )  # a live maintained view, queryable via view_read/view_apply
     srv, thread = serve_tcp(core, args.host, args.port)
     host, port = srv.server_address
     print(f"JOIN-AGG server on {host}:{port} "
-          f"(C1 chain, {args.scale} rows/relation; view 'by_g1' maintained)")
+          f"(C1 chain, {args.scale} rows/relation, engine {args.engine}; "
+          "view 'by_g1' maintained)")
     print("protocol: newline-delimited JSON — see repro/serve/wire.py")
     try:
         thread.join()

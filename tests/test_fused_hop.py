@@ -134,14 +134,15 @@ def test_fused_single_segment_and_tiny_rows():
 def test_fused_odd_blocks_normalize():
     """Non-multiple-of-8 block sizes round up instead of silently
     degrading the slice step (the ``math.gcd`` regression, fused form)."""
-    hop = _random_hop(220, (30, 11), (2, 3), 19, 1, "max")
+    hop = _random_hop(1100, (30, 11), (2, 3), 19, 1, "max")
     _run(*hop, 19, 1, "max", block_e=100, block_s=60, block_r=50)
 
 
 def test_fused_trailing_partial_tiles():
     """Edge/segment/row counts that are not block multiples exercise the
-    padded trailing tiles on every axis."""
-    hop = _random_hop(513, (129, 65), (2, 2), 131, 2, "sum")
+    padded trailing tiles on every axis (block_e rounds up to the 1024
+    edge granule: two full edge tiles and a one-edge partial)."""
+    hop = _random_hop(2049, (129, 65), (2, 2), 131, 2, "sum")
     _run(*hop, 131, 2, "sum", block_e=128, block_s=32, block_r=64)
 
 
@@ -208,29 +209,29 @@ def test_block_normalization_policy():
 
 
 def test_interpret_policy_centralized():
-    """Regression: per-kernel ``interpret=None`` auto-detection used to
-    disagree with the engine's ``_use_ref_kernels`` — an explicit
-    ``interpret=False`` on CPU could mix Pallas-interpret and ref
-    kernels in one program.  Both now resolve through one policy:
-    explicit flags pin the Pallas path (never the ref fallback), and on
-    a CPU host Pallas always runs in interpret mode (no Mosaic target).
-    """
-    from repro.core.jax_engine import _use_ref_kernels
-
+    """One policy decides how a kernel runs, and it never hides the
+    device: ``interpret=True`` is the interpreter, ``interpret=False``
+    means Mosaic-compiled and raises on a CPU backend (it used to turn
+    into interpret mode there), and only auto mode on a CPU backend may
+    substitute the jnp reference kernels in the engines."""
     on_cpu = jax.default_backend() == "cpu"
     assert ops.resolve_interpret(True) is True
     assert ops.resolve_interpret(None) is on_cpu
     if on_cpu:
-        # no Mosaic target on CPU: the explicit flag pins the Pallas
-        # path, and Pallas-on-CPU means the interpreter
-        assert ops.resolve_interpret(False) is True
+        with pytest.raises(RuntimeError, match="interpret=False"):
+            ops.resolve_interpret(False)
+        # the public wrappers reach the same check
+        with pytest.raises(RuntimeError, match="interpret=False"):
+            ops.segment_sum(
+                jnp.ones((8, 2)), jnp.zeros(8, jnp.int32), num_segments=2,
+                interpret=False,
+            )
+    else:
+        assert ops.resolve_interpret(False) is False
     # ref kernels only when NOTHING was pinned and we're on CPU
     assert ops.use_ref_kernels(None) is on_cpu
     assert ops.use_ref_kernels(False) is False
     assert ops.use_ref_kernels(True) is False
-    # the engine delegates to the same policy — they cannot disagree
-    for flag in (None, True, False):
-        assert _use_ref_kernels(flag) == ops.use_ref_kernels(flag)
 
 
 # ----------------------------------------------------------------------
@@ -433,3 +434,34 @@ def test_explain_kernels_section():
     assert "kernels: fused hop megakernel" in ex
     assert "acc=float32" in ex and "tiles e" in ex
     assert "kernels:" not in q.plan(db).explain()
+
+
+def test_no_fitting_tile_raises_to_the_client(monkeypatch, tmp_path):
+    """A hop that no candidate tile fits in VMEM raises, naming the hop
+    shape and the bound, instead of launching default tiles; explain and
+    the verifier report it, and the error reaches a served client."""
+    from repro.analysis.verify import check_kernels
+    from repro.api import Q
+    from repro.kernels import autotune
+    from repro.launch import roofline
+    from repro.relational.relation import Database
+    from repro.serve.server import JoinAggServer
+    from repro.serve.session import Session
+
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "tiles.json"))
+    monkeypatch.setattr(roofline, "VMEM_BYTES", 1 << 16)
+    autotune.reset_cache()
+    try:
+        db = Database.from_mapping(_star_db(n=120))
+        q = (
+            Q.over("R1", "R2", "R3").group_by("R1.g1", "R3.g2")
+            .engine("jax").fused(True)
+        )
+        plan = q.plan(db)
+        assert "no fused-hop tile fits VMEM" in plan.explain()
+        assert any(d.code == "V-KERN" for d in check_kernels(plan))
+        with JoinAggServer(db, workers=1) as srv:
+            with pytest.raises(ValueError, match=r"fused_hop\|.*bound 65536 B"):
+                Session(srv).query(q)
+    finally:
+        autotune.reset_cache()

@@ -5,13 +5,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.ops import coo_spmm, segment_reduce, segment_sum, semiring_matmul
 
 RNG = np.random.default_rng(1)
 
 
-@pytest.mark.parametrize("n,d,s", [(100, 8, 16), (513, 128, 130), (64, 256, 7), (1, 8, 3)])
+@pytest.mark.parametrize("n,d,s", [(100, 8, 16), (2049, 128, 520), (64, 256, 7), (1, 8, 3)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_segment_sum(n, d, s, dtype):
     data = jnp.asarray(RNG.normal(size=(n, d)), dtype=dtype)
@@ -25,7 +25,7 @@ def test_segment_sum(n, d, s, dtype):
 
 
 @pytest.mark.parametrize(
-    "nnz,m,k,n", [(200, 32, 24, 16), (1000, 130, 257, 128), (5, 8, 8, 8)]
+    "nnz,m,k,n", [(200, 32, 24, 16), (2049, 130, 257, 128), (5, 8, 8, 8)]
 )
 @pytest.mark.parametrize("dtype", [jnp.float32])
 def test_coo_spmm(nnz, m, k, n, dtype):
@@ -55,7 +55,7 @@ def test_semiring_matmul(semiring, m, k, n):
 
 
 @pytest.mark.parametrize("kind", ["min", "max"])
-@pytest.mark.parametrize("n,d,s", [(100, 8, 16), (513, 128, 130), (1, 8, 3)])
+@pytest.mark.parametrize("n,d,s", [(100, 8, 16), (2049, 128, 130), (1, 8, 3)])
 def test_segment_reduce(kind, n, d, s):
     data = jnp.asarray(RNG.normal(size=(n, d)), dtype=jnp.float32)
     ids = jnp.asarray(RNG.integers(0, s, size=n), dtype=jnp.int32)
@@ -69,9 +69,11 @@ def test_segment_reduce(kind, n, d, s):
 
 def test_segment_reduce_block_not_multiple_of_kstep():
     """Regression: block_n not divisible by the k-slice step used to drop
-    the trailing rows of every block (12 // 8 == 1 loop step)."""
-    data = jnp.asarray(RNG.normal(size=(24, 4)), dtype=jnp.float32)
-    ids = jnp.asarray(RNG.integers(0, 6, size=24), dtype=jnp.int32)
+    the trailing rows of every block (12 // 8 == 1 loop step).  Two full
+    row blocks and a partial one after rounding to the edge granule."""
+    n = 2 * ops.EDGE_GRANULE + 24
+    data = jnp.asarray(RNG.normal(size=(n, 4)), dtype=jnp.float32)
+    ids = jnp.asarray(RNG.integers(0, 6, size=n), dtype=jnp.int32)
     got = segment_reduce(
         data, ids, num_segments=6, kind="min", block_s=8, block_n=12,
         interpret=True,

@@ -3,7 +3,8 @@ bit-identical to the three-dispatch path and the numpy oracle across
 randomly drawn hop shapes, block sizes, and semiring kinds.
 
 Runs entirely in Pallas interpret mode.  Shapes deliberately cover the
-degenerate corners: trailing partial tiles on every axis, zero-edge
+degenerate corners: trailing partial tiles on every axis (edge counts
+straddle the 1024-edge block granule), zero-edge
 hops, single-segment outputs, child messages with fewer rows than the
 gather tile, and ±inf identity entries in min/max child messages.
 """
@@ -24,7 +25,7 @@ BLOCKS = st.sampled_from([8, 16, 24, 50, 64, 100, 128])
 def hop_cases(draw, kinds=("sum", "min", "max")):
     kind = draw(st.sampled_from(kinds))
     k = draw(st.integers(1, 3)) if kind == "sum" else 1
-    n = draw(st.sampled_from([0, 1, 7, 63, 64, 65, 200]))
+    n = draw(st.sampled_from([0, 1, 7, 64, 200, 1023, 1025, 2049]))
     segs = draw(st.sampled_from([1, 3, 17, 64, 130]))
     nchild = draw(st.integers(0, 2)) if kind == "sum" else draw(st.integers(1, 2))
     rng = np.random.default_rng(draw(st.integers(0, 2**31 - 1)))

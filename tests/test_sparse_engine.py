@@ -59,7 +59,10 @@ def test_sparse_count_matches_oracle(case):
 
 @pytest.mark.parametrize("kind", [Sum, Min, Max])
 def test_sparse_measures_match_oracle(kind):
-    db = measured_db()
+    # R2 holds 1,235 distinct (p, q) and 2,297 distinct (p, q, m) rows,
+    # so its hop spans two or more 1024-edge kernel tiles and the kernels
+    # accumulate across edge-tile revisits
+    db = measured_db(n=2500, b=40)
     q = JoinAggQuery(
         ("R1", "R2", "R3"), (("R1", "g1"), ("R3", "g2")), kind("R2", "m")
     )
