@@ -24,6 +24,7 @@ from typing import Protocol, runtime_checkable
 
 import numpy as np
 
+from repro import obs
 from repro.core.prepare import Prepared
 from repro.core.tensor_engine import (
     ChannelTensorEngine,
@@ -134,17 +135,18 @@ def sparsify(
     offsets: dict[str, int] | None,
 ) -> EngineOutput:
     """Dense ``(*group_dims, k)`` channel array -> sparse EngineOutput."""
-    ci = channels.index(COUNT_CHANNEL)
-    nz = np.nonzero(arr[..., ci] > 0)
-    codes = np.stack(nz, axis=1).astype(np.int64)
-    if offsets:
-        for i, (_, attr) in enumerate(prep.group_attrs):
-            codes[:, i] += offsets.get(attr, 0)
-    return EngineOutput(
-        codes,
-        arr[nz].astype(np.float64),
-        {req: a[nz].astype(np.float64) for req, a in mm.items()},
-    )
+    with obs.span("joinagg.assemble"):
+        ci = channels.index(COUNT_CHANNEL)
+        nz = np.nonzero(arr[..., ci] > 0)
+        codes = np.stack(nz, axis=1).astype(np.int64)
+        if offsets:
+            for i, (_, attr) in enumerate(prep.group_attrs):
+                codes[:, i] += offsets.get(attr, 0)
+        return EngineOutput(
+            codes,
+            arr[nz].astype(np.float64),
+            {req: a[nz].astype(np.float64) for req, a in mm.items()},
+        )
 
 
 class TensorChannelEngine:
@@ -219,10 +221,12 @@ class JaxChannelEngine:
             return self._run_distributed(
                 prep, channels, minmax, cm, mesh, fused=fused
             )
-        choice = choose_jax_path(
-            prep, k=len(channels), memory_budget=memory_budget, stream=stream,
-            measured=cm,
-        )
+        with obs.span("joinagg.plan"):
+            choice = choose_jax_path(
+                prep, k=len(channels), memory_budget=memory_budget,
+                stream=stream, measured=cm,
+            )
+            prog = build_sparse_program(prep, cm, fused=fused)
         # an explicit .fused(True) pins the sparse path: fused hops have
         # no dense-einsum form (REPRO_FUSED alone does not move the
         # dense/sparse choice — it only fuses hops when sparse runs)
@@ -231,7 +235,6 @@ class JaxChannelEngine:
             arr = np.moveaxis(arr.astype(np.float64), 0, -1)
             mm = _shared_minmax(prep, prep.encoded, None, minmax)
             return [sparsify(prep, channels, arr, mm, None)]
-        prog = build_sparse_program(prep, cm, fused=fused)
         if stream is None:
             tiles = [(None, None, None)]
         else:

@@ -30,6 +30,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro import obs
 from repro.aggregates.semiring import AggSpec
 from repro.api.engines import (
     COUNT_CHANNEL,
@@ -261,24 +262,23 @@ class Plan:
         if self.split is not None and mesh is None:
             from repro.planner.split import execute_split
 
-            return _assemble(
-                self,
-                execute_split(
-                    self.prep,
-                    self.split,
-                    self.engine,
-                    self.channels,
-                    fused=self.fused,
-                ),
+            outputs = execute_split(
+                self.prep,
+                self.split,
+                self.engine,
+                self.channels,
+                fused=self.fused,
             )
-        outputs = self.engine.run(
-            self.prep,
-            self.channels,
-            self.minmax,
-            None if mesh is not None else self._resolved_stream(),
-            **kwargs,
-        )
-        return _assemble(self, outputs)
+        else:
+            outputs = self.engine.run(
+                self.prep,
+                self.channels,
+                self.minmax,
+                None if mesh is not None else self._resolved_stream(),
+                **kwargs,
+            )
+        with obs.span("joinagg.assemble"):
+            return _assemble(self, outputs)
 
     def maintain(self):
         """Incremental-maintenance handle(s) for this plan's query.

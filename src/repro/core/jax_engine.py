@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.prepare import Prepared, csr_restrict, grouped_csr, prepare
 from repro.core.query import JoinAggQuery
 from repro.core.tensor_engine import (
@@ -71,6 +72,27 @@ def dense_tensor(prep: Prepared, rel: str, dtype=np.float32) -> np.ndarray:
     return out
 
 
+def _to_device(*arrays) -> list[jax.Array]:
+    """Upload host arrays: every input of a hop or a dense program passes
+    here, under one ``joinagg.h2d`` span, its bytes counted."""
+    with obs.span("joinagg.h2d"):
+        out = [jnp.asarray(a) for a in arrays]
+    obs.count("transfer.h2d_bytes", sum(a.nbytes for a in out))
+    return out
+
+
+def _to_host(out: jax.Array) -> np.ndarray:
+    """Download a kernel's or a program's result: the wait for the chip
+    (``joinagg.wait``), then the copy (``joinagg.d2h``), its bytes
+    counted.  The one sync point per launch, as a bare ``np.asarray``."""
+    with obs.span("joinagg.wait"):
+        out.block_until_ready()
+    with obs.span("joinagg.d2h"):
+        host = np.asarray(out)
+    obs.count("transfer.d2h_bytes", host.nbytes)
+    return host
+
+
 @dataclass
 class DenseProgram:
     """A jit-able closed-form COUNT/SUM program over dense relation tensors."""
@@ -83,8 +105,10 @@ class DenseProgram:
     plan_key: tuple = ()
 
     def input_arrays(self, dtype=np.float32) -> dict[str, jax.Array]:
-        return {r: jnp.asarray(dense_tensor(self.prep, r, dtype))
-                for r in self.prep.encoded}
+        rels = list(self.prep.encoded)
+        return dict(zip(rels, _to_device(
+            *(dense_tensor(self.prep, r, dtype) for r in rels)
+        )))
 
 
 # Plan-keyed program caches.  Repeated executions of structurally equal
@@ -218,10 +242,11 @@ def execute_jax(
             t = np.zeros(dims, dtype=np.float32)
             np.add.at(t, tuple(er.codes[:, i] for i in range(len(er.attrs))),
                       er.payloads["sum"].astype(np.float32))
-            tensors[rel] = jnp.asarray(t)
+            (tensors[rel],) = _to_device(t)
         jitted = _jit_for(prog.plan_key, prog.fn)
-        arr = np.asarray(jitted(tensors))
-        return _decode(prep, arr)
+        with obs.span("joinagg.launch"):
+            out = jitted(tensors)
+        return _decode(prep, _to_host(out))
 
     if mode == "sparse":
         measure = query.agg.measure[0] if kind == "sum" else None
@@ -293,7 +318,10 @@ def execute_jax_channels(
     if not z_rels:  # all-COUNT bundle: one scalar program, replicated
         prog = build_dense_program(prep)
         jitted = _jit_for(prog.plan_key, prog.fn)
-        arr = np.asarray(jitted(prog.input_arrays(dtype)))
+        tensors = prog.input_arrays(dtype)
+        with obs.span("joinagg.launch"):
+            out = jitted(tensors)
+        arr = _to_host(out)
         return np.broadcast_to(arr[None], (k,) + arr.shape).copy()
 
     chplan, root_carries = _channelize_plan(
@@ -303,10 +331,10 @@ def execute_jax_channels(
     key = ("channels", chplan, root)
     fn = _FN_CACHE.get_or_create(key, lambda: _fn_from_plan(chplan, root))
 
-    tensors: dict[str, jax.Array] = {}
+    host: dict[str, np.ndarray] = {}
     for r in prep.encoded:
         if r not in z_rels:
-            tensors[r] = jnp.asarray(dense_tensor(prep, r, dtype))
+            host[r] = dense_tensor(prep, r, dtype)
             continue
         er = prep.encoded[r]
         dims = tuple(prep.dicts[a].size for a in er.attrs)
@@ -317,11 +345,14 @@ def execute_jax_channels(
             tuple(er.codes[:, i] for i in range(len(er.attrs))),
             er.payloads["sum"].astype(dtype),
         )
-        tensors[r] = jnp.asarray(
-            np.stack([pay if channel_measures[c] == r else cnt for c in range(k)])
+        host[r] = np.stack(
+            [pay if channel_measures[c] == r else cnt for c in range(k)]
         )
+    tensors = dict(zip(host, _to_device(*host.values())))
     jitted = _jit_for(key, fn)
-    return np.asarray(jitted(tensors))
+    with obs.span("joinagg.launch"):
+        out = jitted(tensors)
+    return _to_host(out)
 
 
 def _jit_for(key, fn) -> Callable:
@@ -366,16 +397,18 @@ def _ref_spmm_chunked(keys, idx, vals, flat, knum) -> np.ndarray:
 
     n, width = len(keys), flat.shape[1]
     chunk = max(1024, _REF_GATHER_BYTES // max(4 * width, 1))
-    dense = jnp.asarray(flat)
+    (dense,) = _to_device(flat)
     out = None
     for lo in range(0, n, chunk):
         sl = slice(lo, lo + chunk)
-        part = ref.coo_spmm_ref(
-            jnp.asarray(keys[sl], jnp.int32), jnp.asarray(idx[sl], jnp.int32),
-            jnp.asarray(vals[sl]), dense, knum,
+        kk, ii, vv = _to_device(
+            np.asarray(keys[sl], np.int32), np.asarray(idx[sl], np.int32),
+            vals[sl],
         )
+        with obs.span("joinagg.launch"):
+            part = ref.coo_spmm_ref(kk, ii, vv, dense, knum)
         out = part if out is None else out + part
-    return np.asarray(out, np.float32)
+    return _to_host(out)
 
 
 class _CsrHopMixin:
@@ -406,23 +439,29 @@ class _CsrHopMixin:
             for child in self.deco.nodes[rel].children
         }
         er = self.encoded[rel]
-        key_attrs = self._hop_key_attrs(rel, parent)
-        if er is self.prep.encoded.get(rel):
-            view = self.prep.csr_view(rel, key_attrs)
-        else:  # stream tile: build a tile-local view (restricted domains)
-            cache = self.view_cache
-            view = None if cache is None else cache.get((rel, key_attrs))
-            if view is None:
-                view = grouped_csr(er, key_attrs, self._dims(key_attrs))
-                if cache is not None:
-                    cache[(rel, key_attrs)] = view
-        return self.contract_rows(
-            rel,
-            parent,
-            er.codes[view.order],
-            self._weights(rel)[view.order],
-            child_msgs,
-        )
+        # the hop's own host work, its transfers and its launches; the
+        # children's hops are closed spans of their own before this opens
+        with obs.span(
+            "joinagg.hop", rel=rel, kernel=self._hop_kernel(rel),
+            edges=er.num_rows,
+        ):
+            key_attrs = self._hop_key_attrs(rel, parent)
+            if er is self.prep.encoded.get(rel):
+                view = self.prep.csr_view(rel, key_attrs)
+            else:  # stream tile: build a tile-local view (restricted domains)
+                cache = self.view_cache
+                view = None if cache is None else cache.get((rel, key_attrs))
+                if view is None:
+                    view = grouped_csr(er, key_attrs, self._dims(key_attrs))
+                    if cache is not None:
+                        cache[(rel, key_attrs)] = view
+            return self.contract_rows(
+                rel,
+                parent,
+                er.codes[view.order],
+                self._weights(rel)[view.order],
+                child_msgs,
+            )
 
     def _fused_contract(self, w32, gathers, keys, knum, kind, k=1):
         """Run one hop as a single fused Pallas dispatch (DESIGN.md §13):
@@ -438,8 +477,8 @@ class _CsrHopMixin:
             flat = np.ascontiguousarray(m2, np.float32).reshape(
                 m2.shape[0], -1
             )
-            msgs.append(jnp.asarray(flat))
-            idxs.append(jnp.asarray(idx, jnp.int32))
+            msgs.append(flat)
+            idxs.append(np.asarray(idx, np.int32))
             child_rows.append(m2.shape[0])
             child_widths.append(m2.shape[1])
         cfg = autotune.tiles_for(
@@ -461,20 +500,25 @@ class _CsrHopMixin:
                 block_e=cfg.block_e, block_s=cfg.block_s, block_r=cfg.block_r,
             ),
         )
-        out = ops.fused_hop(
-            jnp.asarray(keys, jnp.int32),
-            jnp.asarray(w32),
-            tuple(msgs),
-            tuple(idxs),
-            num_segments=knum,
-            k=k,
-            kind=kind,
-            block_e=cfg.block_e,
-            block_s=cfg.block_s,
-            block_r=cfg.block_r,
-            interpret=self.interpret,
+        nc = len(msgs)
+        keys_d, w_d, *rest = _to_device(
+            np.asarray(keys, np.int32), w32, *msgs, *idxs
         )
-        return np.asarray(out, np.float32)
+        with obs.span("joinagg.launch"):
+            out = ops.fused_hop(
+                keys_d,
+                w_d,
+                tuple(rest[:nc]),
+                tuple(rest[nc:]),
+                num_segments=knum,
+                k=k,
+                kind=kind,
+                block_e=cfg.block_e,
+                block_s=cfg.block_s,
+                block_r=cfg.block_r,
+                interpret=self.interpret,
+            )
+        return _to_host(out)
 
 
 class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
@@ -497,6 +541,24 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
         self.interpret = interpret
         self.fused = fused
 
+    def _uniform(self, w) -> bool:
+        """Every channel weighs each edge alike: the hop can run as one
+        ``coo_spmm`` with the channels riding its columns."""
+        return self.k == 1 or bool((w == w[:, :1]).all())
+
+    def _hop_kernel(self, rel: str) -> str:
+        """The kernel :meth:`_contract_block` dispatches ``rel``'s hop to."""
+        from repro.kernels import ops
+
+        if ops.fused_enabled(self.fused):
+            return "fused_hop"
+        if len(self.deco.nodes[rel].children) == 1 and (
+            rel not in self.weights_override
+            or self._uniform(self.weights_override[rel])
+        ):
+            return "coo_spmm"
+        return "segment_sum"
+
     def _contract_block(self, weights, gathers, keys, knum):
         from repro.kernels import ops, ref
         from repro.kernels.ops import coo_spmm, segment_sum
@@ -517,8 +579,7 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
             )
             return out.reshape(knum, width, self.k)
         use_ref = ops.use_ref_kernels(self.interpret)
-        uniform = self.k == 1 or bool((w32 == w32[:, :1]).all())
-        if len(gathers) == 1 and uniform:
+        if len(gathers) == 1 and self._uniform(w32):
             m2, idx = gathers[0]  # m2 (rows, width, k)
             rows, width = m2.shape[0], m2.shape[1]
             if rows < _INT32_LIMIT:
@@ -536,12 +597,16 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
                     out = _ref_spmm_chunked(keys, idx, w32[:, 0], flat, knum)
                 else:
                     kk, vv, ii = _pad_edges(keys, w32[:, 0], idx)
-                    out = coo_spmm(
-                        jnp.asarray(kk), jnp.asarray(ii), jnp.asarray(vv),
-                        jnp.asarray(flat), num_rows=knum,
-                        interpret=self.interpret,
+                    args = _to_device(
+                        np.asarray(kk, np.int32), np.asarray(ii, np.int32),
+                        vv, flat,
                     )
-                return np.asarray(out, np.float32).reshape(knum, width, self.k)
+                    with obs.span("joinagg.launch"):
+                        out = coo_spmm(
+                            *args, num_rows=knum, interpret=self.interpret
+                        )
+                    out = _to_host(out)
+                return out.reshape(knum, width, self.k)
         # general hop: row-aligned product of gathered child rows, then a
         # device-side segment reduction into the CSR row keys; the edge
         # axis is chunked so the per-edge product temp stays bounded by
@@ -574,16 +639,17 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
                 ),
             )
             if use_ref:
-                part = ref.segment_sum_ref(
-                    jnp.asarray(flat), jnp.asarray(keys[sl], jnp.int32), knum
-                )
+                vv, kk = _to_device(flat, np.asarray(keys[sl], np.int32))
+                with obs.span("joinagg.launch"):
+                    part = ref.segment_sum_ref(vv, kk, knum)
             else:
                 kk, vv, _ = _pad_edges(keys[sl], flat, None)
-                part = segment_sum(
-                    jnp.asarray(vv), jnp.asarray(kk), num_segments=knum,
-                    interpret=self.interpret,
-                )
-            out += np.asarray(part, np.float32).reshape(knum, width, self.k)
+                vv, kk = _to_device(vv, np.asarray(kk, np.int32))
+                with obs.span("joinagg.launch"):
+                    part = segment_sum(
+                        vv, kk, num_segments=knum, interpret=self.interpret
+                    )
+            out += _to_host(part).reshape(knum, width, self.k)
         return out
 
 
@@ -607,6 +673,12 @@ class _MinMaxKernelEngine(_CsrHopMixin, TensorEngine):
         self.interpret = interpret
         self.fused = fused
         self.ident = np.inf if kind == "min" else -np.inf
+
+    def _hop_kernel(self, rel: str) -> str:
+        """The kernel :meth:`_contract_block` dispatches ``rel``'s hop to."""
+        from repro.kernels import ops
+
+        return "fused_hop" if ops.fused_enabled(self.fused) else "segment_reduce"
 
     def _weights(self, rel):
         er = self.encoded[rel]
@@ -661,17 +733,18 @@ class _MinMaxKernelEngine(_CsrHopMixin, TensorEngine):
                 red.at(out, keys[sl], vals)
                 continue
             if use_ref:
-                part = ref.segment_reduce_ref(
-                    jnp.asarray(vals), jnp.asarray(keys[sl], jnp.int32),
-                    knum, self.kind,
-                )
+                vv, kk = _to_device(vals, np.asarray(keys[sl], np.int32))
+                with obs.span("joinagg.launch"):
+                    part = ref.segment_reduce_ref(vv, kk, knum, self.kind)
             else:
                 kk, vv, _ = _pad_edges(keys[sl], vals, None)
-                part = segment_reduce(
-                    jnp.asarray(vv), jnp.asarray(kk), num_segments=knum,
-                    kind=self.kind, interpret=self.interpret,
-                )
-            out = red(out, np.asarray(part, np.float32))
+                vv, kk = _to_device(vv, np.asarray(kk, np.int32))
+                with obs.span("joinagg.launch"):
+                    part = segment_reduce(
+                        vv, kk, num_segments=knum, kind=self.kind,
+                        interpret=self.interpret,
+                    )
+            out = red(out, _to_host(part))
         return out
 
 
@@ -709,18 +782,19 @@ class SparseProgram:
         self, encoded=None, domains=None, view_cache: dict | None = None
     ) -> np.ndarray:
         """One leaves→root kernel pass; returns ``(*group_dims, k)`` f32."""
-        encoded = self.prep.encoded if encoded is None else encoded
-        eng = _KernelChannelEngine(
-            self.prep,
-            self.k,
-            channel_weight_matrices(encoded, self.channel_measures),
-            domains=domains,
-            encoded=encoded,
-            interpret=self.interpret,
-            fused=self.fused,
-        )
-        eng.view_cache = view_cache
-        return eng.run()
+        with obs.span("joinagg.walk", kind="sum"):
+            encoded = self.prep.encoded if encoded is None else encoded
+            eng = _KernelChannelEngine(
+                self.prep,
+                self.k,
+                channel_weight_matrices(encoded, self.channel_measures),
+                domains=domains,
+                encoded=encoded,
+                interpret=self.interpret,
+                fused=self.fused,
+            )
+            eng.view_cache = view_cache
+            return eng.run()
 
     def run_minmax(
         self, kind: str, rel_m: str, encoded=None, domains=None,
@@ -728,15 +802,16 @@ class SparseProgram:
     ) -> np.ndarray:
         """MIN/MAX(rel_m) over canonical group axes; unreached groups
         hold 0.0 — mask with a COUNT support before use."""
-        encoded = self.prep.encoded if encoded is None else encoded
-        eng = _MinMaxKernelEngine(
-            self.prep, kind, rel_m,
-            domains=domains, encoded=encoded, interpret=self.interpret,
-            fused=self.fused,
-        )
-        eng.view_cache = view_cache
-        arr = eng.run()
-        return np.where(np.isfinite(arr), arr, 0.0)
+        with obs.span("joinagg.walk", kind=kind):
+            encoded = self.prep.encoded if encoded is None else encoded
+            eng = _MinMaxKernelEngine(
+                self.prep, kind, rel_m,
+                domains=domains, encoded=encoded, interpret=self.interpret,
+                fused=self.fused,
+            )
+            eng.view_cache = view_cache
+            arr = eng.run()
+            return np.where(np.isfinite(arr), arr, 0.0)
 
     def run_stream(self, attr: str, tile: int):
         """Yield ``(encoded, domains, offsets)`` per group-axis row tile;

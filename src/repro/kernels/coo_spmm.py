@@ -115,5 +115,6 @@ def coo_spmm(
         out_shape=jax.ShapeDtypeStruct((m_total, n), dense.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
+        name="coo_spmm",  # the custom call's name in HLO and device traces
     )(rows.astype(jnp.int32), cols.astype(jnp.int32), vals.astype(dense.dtype), dense)
     return out[:num_rows]

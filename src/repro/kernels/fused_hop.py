@@ -361,5 +361,6 @@ def fused_hop(
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
+        name="fused_hop",  # the custom call's name in HLO and device traces
     )(keys, weights, *idxs, *msgs, *(jnp.asarray(m) for m in exp_mats))
     return out[:num_segments]

@@ -24,8 +24,9 @@ to be duplicated (and could disagree) per kernel file:
   (``record_dispatch``/``dispatch_counts``) that the engines bump per
   emitted kernel launch; benchmark table 15 uses them as the
   CPU-measurable proxy for the fused path's 3-dispatches→1 reduction.
-  Hop kernel calls also leave their shapes (``dispatched_hops``), which
-  the chip smoke prints.
+  They are the ``dispatch.*`` entries of the program's counter registry
+  (:mod:`repro.obs`).  Hop kernel calls also leave their shapes
+  (``dispatched_hops``), which the chip smoke prints.
 
 The kernel modules import this policy lazily (inside their wrapper
 bodies) and this module re-exports the kernels at the bottom, so either
@@ -39,6 +40,8 @@ import os
 import threading
 
 import jax
+
+from repro import obs
 
 #: granule for the k-slice fori_loop inside segment_reduce /
 #: semiring_matmul / fused min-max hops; blocks are rounded up to a
@@ -140,8 +143,8 @@ def k_step_for(block: int) -> int:
 # dispatch accounting (table 15's currency)
 # ----------------------------------------------------------------------
 
-_dispatch_lock = threading.Lock()
-_dispatch_counts: dict[str, int] = {}
+_DISPATCH = "dispatch."
+_hops_lock = threading.Lock()
 #: the newest kernel dispatches' shapes (bounded: a server records forever)
 _dispatch_hops: collections.deque[dict] = collections.deque(maxlen=256)
 
@@ -152,26 +155,29 @@ def record_dispatch(stage: str, n: int = 1, hop: dict | None = None) -> None:
     given, describes one hop kernel call — ``kernel``, ``edges`` (as the
     engine passes them), ``segments``, ``child_rows``, ``width`` (output
     columns) and any non-default blocks — for :func:`dispatched_hops`."""
-    with _dispatch_lock:
-        _dispatch_counts[stage] = _dispatch_counts.get(stage, 0) + n
-        if hop is not None:
+    obs.count(_DISPATCH + stage, n)
+    if hop is not None:
+        with _hops_lock:
             _dispatch_hops.append(hop)
 
 
 def dispatch_counts() -> dict[str, int]:
-    with _dispatch_lock:
-        return dict(_dispatch_counts)
+    return {
+        name[len(_DISPATCH):]: n
+        for name, n in obs.counters().items()
+        if name.startswith(_DISPATCH)
+    }
 
 
 def dispatched_hops() -> list[dict]:
     """Shapes of the hop kernel calls since the last reset, oldest first."""
-    with _dispatch_lock:
+    with _hops_lock:
         return list(_dispatch_hops)
 
 
 def reset_dispatch_counts() -> None:
-    with _dispatch_lock:
-        _dispatch_counts.clear()
+    obs.reset(_DISPATCH)
+    with _hops_lock:
         _dispatch_hops.clear()
 
 

@@ -137,5 +137,6 @@ def segment_reduce(
         scratch_shapes=[pltpu.VMEM((block_n, 1), jnp.int32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
+        name="segment_reduce",  # the custom call's name in HLO and device traces
     )(segment_ids.astype(jnp.int32), data)
     return out[:num_segments]

@@ -85,5 +85,6 @@ def segment_sum(
         out_shape=jax.ShapeDtypeStruct((s_total, d), data.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
+        name="segment_sum",  # the custom call's name in HLO and device traces
     )(segment_ids.astype(jnp.int32), data)
     return out[:num_segments]

@@ -110,5 +110,6 @@ def semiring_matmul(
         out_shape=jax.ShapeDtypeStruct((a.shape[0], b.shape[1]), a.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
+        name="semiring_matmul",  # the custom call's name in HLO and device traces
     )(a, b)
     return out[:m, :n]

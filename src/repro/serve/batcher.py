@@ -30,6 +30,7 @@ import time
 from dataclasses import dataclass, field, replace
 from typing import Callable
 
+from repro import obs
 from repro.aggregates.semiring import Count
 from repro.api.plan import AggResult, Plan
 from repro.relational.relation import Relation
@@ -71,6 +72,9 @@ class _Pending:
     spec: "object"  # the Q builder
     shape_key: tuple  # full plan-shape key
     future: "object"  # concurrent.futures.Future
+    # when the client submitted it; a worker's start minus this is the
+    # query's wait in the fusion window and the pool's queue
+    submitted: float = field(default_factory=time.perf_counter)
 
 
 @dataclass
@@ -190,13 +194,16 @@ def run_group(items: list[_Pending], lookup_plan, stats: BatchStats) -> None:
     live = [it for it in items if not it.future.cancelled()]
     if not live:
         return
+    start = time.perf_counter()
+    obs.count("serve.queries", len(live))
+    obs.count("serve.queue_wait_s", sum(start - it.submitted for it in live))
     try:
         if len(live) == 1:
             stats.add(solo=1)
             _resolve_solo(live[0], lookup_plan)
             return
         if all(it.shape_key == live[0].shape_key for it in live):
-            result = lookup_plan(live[0].spec).execute()
+            result = _plan(lookup_plan, live[0].spec).execute()
             stats.add(
                 batches=1,
                 fused_queries=len(live),
@@ -212,8 +219,13 @@ def run_group(items: list[_Pending], lookup_plan, stats: BatchStats) -> None:
                 it.future.set_exception(e)
 
 
+def _plan(lookup_plan, spec) -> Plan:
+    with obs.span("joinagg.plan"):
+        return lookup_plan(spec)
+
+
 def _resolve_solo(item: _Pending, lookup_plan) -> None:
-    item.future.set_result(lookup_plan(item.spec).execute())
+    item.future.set_result(_plan(lookup_plan, item.spec).execute())
 
 
 def _run_merged(items: list[_Pending], lookup_plan, stats: BatchStats) -> None:
@@ -225,7 +237,7 @@ def _run_merged(items: list[_Pending], lookup_plan, stats: BatchStats) -> None:
             merged_aggs.append((f"a{i}__{name}", agg))
     merged_spec = replace(items[0].spec, aggs=tuple(merged_aggs))
     try:
-        plan: Plan = lookup_plan(merged_spec)
+        plan = _plan(lookup_plan, merged_spec)
         merged = plan.execute()
     except Exception:
         # planner rejected the union (e.g. two bundles measure different

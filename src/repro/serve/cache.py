@@ -20,9 +20,12 @@ pulling the server in:
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable
+
+from repro import obs
 
 
 @dataclass
@@ -248,21 +251,30 @@ class PlanCache:
         return len(self._lru)
 
     def lookup(self, spec, db, generation: int = 0, stats_generation: int = 0):
-        from repro.api.plan import compile_plan
-
         key = plan_shape_key(spec, generation, stats_generation)
         if key is None:
             with self._stats_lock:
                 self.stats.bypasses += 1
                 self.stats.compiles += 1
-            return compile_plan(spec, db)
+            return _build_plan(spec, db)
 
         def build():
             with self._stats_lock:
                 self.stats.compiles += 1
-            return compile_plan(spec, db)
+            return _build_plan(spec, db)
 
         return self._lru.get_or_create(key, build)
 
     def clear(self) -> None:
         self._lru.clear()
+
+
+def _build_plan(spec, db):
+    """Compile one plan, counted in ``plan.builds`` and ``plan.build_s``."""
+    from repro.api.plan import compile_plan
+
+    t = time.perf_counter()
+    plan = compile_plan(spec, db)
+    obs.count("plan.builds")
+    obs.count("plan.build_s", time.perf_counter() - t)
+    return plan

@@ -33,6 +33,7 @@ import warnings
 from concurrent.futures import Future, ThreadPoolExecutor
 from pathlib import Path
 
+from repro import obs
 from repro.relational.relation import Database, Relation
 from repro.relational.source import as_source, is_source
 from repro.serve import wire
@@ -246,6 +247,7 @@ class JoinAggServer:
             "fusion": self._batcher.stats.snapshot(),
             "jit_cache": jax_engine.jit_cache_stats(),
             "views": views,
+            "counters": obs.counters(),
         }
 
     def close(self) -> None:

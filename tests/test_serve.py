@@ -184,6 +184,24 @@ def test_tcp_roundtrip_register_query_and_errors(db):
             tcp.shutdown()
 
 
+def test_stats_carry_the_program_counters(db):
+    q = QUERIES["count"]
+    with JoinAggServer(db, workers=2) as srv:
+        before = srv.stats()["counters"].get("serve.queries", 0)
+        srv.query(q)
+        local = srv.stats()["counters"]
+        assert local["serve.queries"] == before + 1
+        assert local["plan.builds"] >= 1 and local["serve.queue_wait_s"] >= 0
+        tcp, _ = serve_tcp(srv)
+        try:
+            with connect(*tcp.server_address) as c:
+                remote = c.server_stats()["counters"]
+        finally:
+            tcp.shutdown()
+    assert remote["serve.queries"] == local["serve.queries"]
+    assert set(local) <= set(remote)
+
+
 def test_closed_server_rejects_queries(db):
     srv = JoinAggServer(db, workers=2)
     srv.close()

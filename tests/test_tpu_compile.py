@@ -12,7 +12,18 @@ the compiled program calls the Mosaic kernel.
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU
 library, and each test worker imports every test file.
+
+Each kernel's ``pallas_call`` carries an explicit ``name``: it names the
+custom call in the compiled HLO, and so the op in a device trace, which
+the chip benchmark's ``hop_kernel_ms`` finds by that label.  One test
+checks the name in each wrapper's jaxpr on the CPU; another compiles
+each kernel for the described chip and reads the label back.
 """
+import importlib.util
+import re
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -129,3 +140,86 @@ def test_fused_hop_compiles(compile_for_tpu, kind, k, hop):
             interpret=False,
         )
     )
+
+
+# ----------------------------------------------------------------------
+# kernel names: the label a device trace gives each custom call
+# ----------------------------------------------------------------------
+
+
+def _named_calls(sds):
+    """A small call of each Pallas kernel: ``(name, wrapper, args,
+    kwargs)``, the arguments abstract."""
+    i32 = jnp.int32
+    e, rows, width, segs = 2048, 256, 128, 128
+    fused = autotune.model_tiles_for(autotune.hop_shape(
+        edges=e, child_rows=(rows,), k=1, kind="sum",
+        child_widths=(width,), num_segments=segs,
+    ))
+    return [
+        ("coo_spmm", ops.coo_spmm,
+         (sds((e,), i32), sds((e,), i32), sds((e,)), sds((rows, width))),
+         dict(num_rows=segs)),
+        ("segment_sum", ops.segment_sum,
+         (sds((e, width)), sds((e,), i32)), dict(num_segments=segs)),
+        ("segment_reduce", ops.segment_reduce,
+         (sds((e, width)), sds((e,), i32)), dict(num_segments=segs, kind="min")),
+        ("fused_hop", ops.fused_hop,
+         (sds((e,), i32), sds((e, 1)), (sds((rows, width)),), (sds((e,), i32),)),
+         dict(num_segments=segs, k=1, kind="sum", block_e=fused.block_e,
+              block_s=fused.block_s, block_r=fused.block_r)),
+        ("semiring_matmul", ops.semiring_matmul,
+         (sds((rows, width)), sds((width, rows))), dict(semiring="add_mul")),
+    ]
+
+
+KERNELS = ["coo_spmm", "segment_sum", "segment_reduce", "fused_hop", "semiring_matmul"]
+
+
+def _call(sds, name):
+    (call,) = [c[1:] for c in _named_calls(sds) if c[0] == name]
+    return call
+
+
+def _pallas_names(jaxpr) -> list:
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_pallas_names(sub))
+    return out
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_pallas_call_carries_the_kernel_name(name):
+    fn, args, kwargs = _call(
+        lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype), name
+    )
+    traced = fn.trace(*args, **kwargs, interpret=True)
+    assert _pallas_names(traced.jaxpr.jaxpr) == [name]
+
+
+def _op_label():
+    """The chip benchmark's label of a trace op (``%name.1 = ...`` ->
+    ``name``), read from its own file."""
+    path = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" / "tracing.py"
+    spec = importlib.util.spec_from_file_location("chip_tracing", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # its dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod.op_label
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_compiled_custom_call_is_labelled_by_the_kernel_name(compile_for_tpu, name):
+    sds, compile_text = compile_for_tpu
+    fn, args, kwargs = _call(sds, name)
+    text = compile_text(fn.lower(*args, **kwargs, interpret=False))
+    label = _op_label()
+    # an instruction as the trace names it: the HLO line without ROOT
+    calls = [
+        label(line.strip().removeprefix("ROOT ")) for line in text.splitlines()
+        if re.search(r"custom-call\(", line) and "tpu_custom_call" in line
+    ]
+    assert calls == [name]
