@@ -597,13 +597,17 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
                     out = _ref_spmm_chunked(keys, idx, w32[:, 0], flat, knum)
                 else:
                     kk, vv, ii = _pad_edges(keys, w32[:, 0], idx)
-                    args = _to_device(
-                        np.asarray(kk, np.int32), np.asarray(ii, np.int32),
-                        vv, flat,
+                    kk = np.asarray(kk, np.int32)
+                    # the kernel's schedule, built from the host's keys and
+                    # uploaded with them
+                    *args, pairs = _to_device(
+                        kk, np.asarray(ii, np.int32), vv, flat,
+                        ops.spmm_pair_list(kk, knum),
                     )
                     with obs.span("joinagg.launch"):
                         out = coo_spmm(
-                            *args, num_rows=knum, interpret=self.interpret
+                            *args, num_rows=knum, pairs=pairs,
+                            interpret=self.interpret,
                         )
                     out = _to_host(out)
                 return out.reshape(knum, width, self.k)

@@ -64,7 +64,7 @@ class KernelDeltaEngine(TensorEngine):
     ) -> np.ndarray:
         import jax.numpy as jnp
 
-        from repro.kernels.ops import coo_spmm, segment_sum
+        from repro.kernels.ops import coo_spmm, segment_sum, spmm_pair_list
 
         n = len(weights)
         if knum >= 2**31:  # int32 segment-id space of the kernels
@@ -81,7 +81,7 @@ class KernelDeltaEngine(TensorEngine):
             out = coo_spmm(
                 jnp.asarray(k), jnp.asarray(idx), jnp.asarray(w),
                 jnp.asarray(m2, dtype=jnp.float32), num_rows=knum,
-                interpret=self.interpret,
+                pairs=spmm_pair_list(k, knum), interpret=self.interpret,
             )
         else:
             vals = w32.reshape(n, 1)

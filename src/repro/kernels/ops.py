@@ -184,7 +184,12 @@ def reset_dispatch_counts() -> None:
 # re-exports: ops is the stable import surface for all kernels; these
 # live at the bottom so the kernel modules can import the policy
 # functions above from inside their wrapper bodies without a cycle
-from repro.kernels.coo_spmm import coo_spmm  # noqa: E402
+from repro.kernels.coo_spmm import (  # noqa: E402
+    coo_spmm,
+    coo_spmm_pairs,
+    pair_grid_len,
+    spmm_pair_list,
+)
 from repro.kernels.fused_hop import fused_hop  # noqa: E402
 from repro.kernels.segment_reduce import segment_reduce  # noqa: E402
 from repro.kernels.segment_sum import segment_sum  # noqa: E402
@@ -192,6 +197,7 @@ from repro.kernels.semiring_matmul import semiring_matmul  # noqa: E402
 
 __all__ = [
     "coo_spmm",
+    "coo_spmm_pairs",
     "EDGE_GRANULE",
     "dispatch_counts",
     "dispatched_hops",
@@ -199,6 +205,7 @@ __all__ = [
     "fused_hop",
     "k_step_for",
     "normalize_block",
+    "pair_grid_len",
     "record_dispatch",
     "reset_dispatch_counts",
     "resolve_interpret",
@@ -206,5 +213,6 @@ __all__ = [
     "segment_sum",
     "segment_reduce",
     "semiring_matmul",
+    "spmm_pair_list",
     "use_ref_kernels",
 ]

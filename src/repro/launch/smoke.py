@@ -95,7 +95,8 @@ def _blocks(kernel: str) -> dict[str, int]:
 
 def hop_table(hops: list[dict]) -> list[dict]:
     """The hop kernel calls one query dispatched (``ops.dispatched_hops``)
-    with each call's grid cells at the blocks it ran with, and two host
+    with each call's full grid cells at the blocks it ran with (a
+    ``coo_spmm`` on row-sorted edges runs fewer), and two host
     estimates: the f32 bytes of its child messages and of its
     ``(segments, width)`` output, before any device padding."""
     cdiv = lambda a, b: -(-a // b)  # noqa: E731
@@ -180,11 +181,13 @@ def _check_chip_kernels(log: Callable[[str], None], hop: dict) -> None:
             f"kernels on backend {jax.default_backend()!r}"
         )
     e, i32 = hop["edges"], jnp.int32
-    text = ops.coo_spmm.lower(
+    # the pair-grid form, which the engine dispatches for its row-sorted hops
+    text = ops.coo_spmm_pairs.lower(
         jax.ShapeDtypeStruct((e,), i32),
         jax.ShapeDtypeStruct((e,), i32),
         jax.ShapeDtypeStruct((e,), jnp.float32),
         jax.ShapeDtypeStruct((hop["child_rows"], hop["width"]), jnp.float32),
+        jax.ShapeDtypeStruct((2, ops.pair_grid_len(e, hop["segments"])), i32),
         num_rows=hop["segments"],
     ).as_text()
     if "tpu_custom_call" not in text:

@@ -9,7 +9,8 @@
 * :func:`count` / :func:`counters` / :func:`reset` — the process-wide
   counter registry, cumulative until reset.  Names are dotted by layer:
   ``serve.*`` (queries, queue wait), ``plan.*`` (plan-cache builds),
-  ``transfer.*`` (host↔device bytes), ``dispatch.*`` (the kernel
+  ``transfer.*`` (host↔device bytes), ``kernel.*`` (grid steps a
+  kernel ran against its full grid), ``dispatch.*`` (the kernel
   dispatch accounting that :mod:`repro.kernels.ops` views).
 
 There is no switch: spans are always on, counters always count.

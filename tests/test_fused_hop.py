@@ -180,7 +180,7 @@ def test_dimension_semantics_declared():
         semiring_matmul,
     )
 
-    assert coo_spmm.DIM_SEMANTICS == ("parallel", "arbitrary", "arbitrary")
+    assert coo_spmm.DIM_SEMANTICS == ("arbitrary", "arbitrary")
     assert segment_sum.DIM_SEMANTICS == ("parallel", "arbitrary")
     assert segment_reduce.DIM_SEMANTICS == ("parallel", "arbitrary")
     assert fused_mod.DIM_SEMANTICS == ("parallel", "arbitrary")

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.kernels import ops, ref
 from repro.kernels.ops import coo_spmm, segment_reduce, segment_sum, semiring_matmul
 
@@ -24,6 +25,15 @@ def test_segment_sum(n, d, s, dtype):
     )
 
 
+def _spmm(rows, cols, vals, dense, m, block_m=128, block_e=1024, **blocks):
+    """``coo_spmm`` in interpret mode with its host rows' pair list."""
+    pairs = ops.spmm_pair_list(np.asarray(rows), m, block_m, block_e)
+    return coo_spmm(
+        rows, cols, vals, dense, num_rows=m, pairs=pairs, block_m=block_m,
+        block_e=block_e, interpret=True, **blocks,
+    )
+
+
 @pytest.mark.parametrize(
     "nnz,m,k,n", [(200, 32, 24, 16), (2049, 130, 257, 128), (5, 8, 8, 8)]
 )
@@ -33,10 +43,134 @@ def test_coo_spmm(nnz, m, k, n, dtype):
     cols = jnp.asarray(RNG.integers(0, k, size=nnz), dtype=jnp.int32)
     vals = jnp.asarray(RNG.integers(1, 5, size=nnz), dtype=dtype)
     dense = jnp.asarray(RNG.normal(size=(k, n)), dtype=dtype)
-    got = coo_spmm(rows, cols, vals, dense, num_rows=m,
-                   block_m=16, block_e=64, block_k=32, interpret=True)
+    got = _spmm(rows, cols, vals, dense, m, block_m=16, block_e=64, block_k=32)
     want = ref.coo_spmm_ref(rows, cols, vals, dense, m)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+# ----------------------------------------------------------------------
+# coo_spmm's pair grid: the (output tile, edge block) cells that hold an
+# edge, and the long list where rows are too scattered for the short one
+# ----------------------------------------------------------------------
+
+SPMM_BLOCKS = dict(block_m=16, block_e=1024, block_k=16)
+
+
+def _sorted_padded_tail():
+    rows = np.sort(RNG.integers(0, 200, 2500))
+    return np.r_[rows, np.full(3072 - 2500, -1)], 200
+
+
+def _empty_tiles():
+    # rows 0–15 and 100–115: tiles 1–5 and 8–12 hold no edge
+    return np.sort(RNG.choice(np.r_[0:16, 100:116], 2100)), 200
+
+
+def _one_row():
+    return np.full(2100, 37), 64
+
+
+def _block_spans_tiles():
+    # one 1024-edge block over 63 output tiles
+    return np.sort(RNG.integers(0, 1000, 1024)), 1000
+
+
+def _unsorted():
+    return RNG.integers(0, 300, 3000), 300
+
+
+SPMM_CASES = {
+    "sorted_padded_tail": (_sorted_padded_tail, False),
+    "empty_tiles": (_empty_tiles, False),
+    "one_row": (_one_row, False),
+    "block_spans_tiles": (_block_spans_tiles, False),
+    "unsorted_full_grid": (_unsorted, True),
+}
+
+
+def _spmm_operands(rows, k=40, n=8):
+    nnz = len(rows)
+    return (
+        jnp.asarray(rows, jnp.int32),
+        jnp.asarray(RNG.integers(0, k, nnz), jnp.int32),
+        jnp.asarray(RNG.integers(1, 5, nnz), jnp.float32),
+        jnp.asarray(RNG.integers(0, 9, (k, n)), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("case", list(SPMM_CASES))
+def test_coo_spmm_pair_grid(case):
+    """Exact on integer-valued f32 against the reference, with the long
+    list, as many steps as the full grid, only where the rows' pairs
+    exceed the short one."""
+    build, full = SPMM_CASES[case]
+    rows, m = build()
+    args = _spmm_operands(rows)
+    obs.reset("kernel.")
+    got = np.asarray(_spmm(*args, m, **SPMM_BLOCKS))
+    want = np.asarray(ref.coo_spmm_ref(*args, m))
+    assert np.array_equal(got, want)
+    c = obs.counters()
+    assert c.get("kernel.coo_spmm_full", 0) == (1 if full else 0)
+    if full:
+        assert c["kernel.coo_spmm_steps"] == c["kernel.coo_spmm_grid_steps"]
+    else:  # never more steps than the full grid
+        assert 0 < c["kernel.coo_spmm_steps"] <= c["kernel.coo_spmm_grid_steps"]
+    # tiles no edge lands in come back zero
+    hit = np.zeros(m, bool)
+    hit[rows[(rows >= 0) & (rows < m)]] = True
+    assert not got[~hit].any()
+
+
+@pytest.mark.parametrize("nnz,m", [(2500, 200), (4096, 30), (1024, 5000), (9000, 9000)])
+def test_spmm_pair_list_fits_sorted_rows(nnz, m):
+    """Row-sorted edges with a padded tail never need more than
+    ``m_tiles + e_tiles - 1`` entries, and the list names exactly the
+    cells that hold an edge, sorted, plus one entry per empty tile."""
+    bm, be = SPMM_BLOCKS["block_m"], SPMM_BLOCKS["block_e"]
+    rows = np.sort(RNG.integers(0, m, nnz))
+    rows = np.r_[rows, np.full(-nnz % be, -1)]
+    m_tiles, e_tiles = -(-m // bm), len(rows) // be
+    pairs = ops.spmm_pair_list(rows, m, block_m=bm, block_e=be)
+    assert pairs.shape == (2, m_tiles + e_tiles - 1)
+    assert pairs.shape[1] == ops.pair_grid_len(nnz, m, block_m=bm, block_e=be)
+    tile, code = pairs
+    assert np.all(np.diff(tile) >= 0)
+    live = {(t, c) for t, c in zip(tile, code) if c >= 0}
+    ids = np.flatnonzero(rows >= 0)
+    assert live == set(zip(rows[ids] // bm, ids // be))
+    assert set(tile) == set(range(m_tiles))
+
+
+@pytest.mark.parametrize(
+    "built_at",
+    [dict(block_m=32, block_e=1024), dict(block_m=16, block_e=2048)],
+    ids=["block_m", "block_e"],
+)
+def test_coo_spmm_refuses_a_list_built_at_other_blocks(built_at):
+    """A pair list built at other blocks than the call's has neither of
+    the lengths its shapes allow: the call raises instead of leaving
+    tiles unwritten."""
+    rows, m = _sorted_padded_tail()
+    args = _spmm_operands(rows)
+    pairs = ops.spmm_pair_list(rows, m, **built_at)
+    with pytest.raises(ValueError, match="pair list"):
+        coo_spmm(*args, num_rows=m, pairs=pairs, interpret=True, **SPMM_BLOCKS)
+
+
+def test_coo_spmm_counts_steps_on_the_host():
+    """Each call counts its steps, a cached compile's too: the counting
+    is on the host, not in the traced function."""
+    rows, m = _sorted_padded_tail()
+    args = _spmm_operands(rows)
+    obs.reset("kernel.")
+    _spmm(*args, m, **SPMM_BLOCKS)
+    once = obs.counters()
+    _spmm(*args, m, **SPMM_BLOCKS)
+    twice = obs.counters()
+    assert once["kernel.coo_spmm_steps"] > 0
+    for name in ("kernel.coo_spmm_steps", "kernel.coo_spmm_grid_steps"):
+        assert twice[name] == 2 * once[name], name
 
 
 @pytest.mark.parametrize("semiring", ["add_mul", "max_add", "min_add", "or_and"])
@@ -109,6 +243,6 @@ def test_spmm_counts_exact_int_in_f32():
     cols = jnp.asarray(RNG.integers(0, k, size=nnz), dtype=jnp.int32)
     vals = jnp.asarray(RNG.integers(1, 100, size=nnz), dtype=jnp.float32)
     dense = jnp.asarray(RNG.integers(0, 100, size=(k, n)), dtype=jnp.float32)
-    got = coo_spmm(rows, cols, vals, dense, num_rows=m, interpret=True)
+    got = _spmm(rows, cols, vals, dense, m)
     want = ref.coo_spmm_ref(rows, cols, vals, dense, m)
     assert np.array_equal(np.asarray(got), np.asarray(want))

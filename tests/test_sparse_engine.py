@@ -90,6 +90,23 @@ def test_kernels_mode_sum_regression():
     assert got == want
 
 
+def test_sparse_hops_run_coo_spmm_on_its_pair_grid():
+    """The engine hands ``coo_spmm`` its hops in grouped-CSR order, so
+    every call runs the pair grid (no full-grid fallback) and the answer
+    stays exact."""
+    from repro import obs
+
+    db = measured_db(n=2500, b=40)
+    q = JoinAggQuery(("R1", "R2", "R3"), (("R1", "g1"), ("R3", "g2")))
+    obs.reset("kernel.")
+    assert_same(
+        execute_jax(q, db, mode="sparse", interpret=True), oracle_joinagg(q, db)
+    )
+    c = obs.counters()
+    assert 0 < c["kernel.coo_spmm_steps"] <= c["kernel.coo_spmm_grid_steps"]
+    assert "kernel.coo_spmm_full" not in c
+
+
 def test_sparse_matches_dense_bit_identical():
     db, q = CASES["chain"]()
     sparse = execute_jax(q, db, mode="sparse", interpret=True)

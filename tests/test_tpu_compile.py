@@ -84,15 +84,35 @@ def compile_for_tpu(one_chip, monkeypatch):
 
 
 def test_coo_spmm_compiles(compile_for_tpu):
+    """The pair-grid form, which the engine dispatches for row-sorted
+    hops, with its pair list at the static length it uploads."""
     sds, compile_text = compile_for_tpu
     i32 = jnp.int32
+    pairs = ops.pair_grid_len(EDGES, LINEITEM["segments"])
     compile_text(
-        ops.coo_spmm.lower(
+        ops.coo_spmm_pairs.lower(
             sds((EDGES,), i32), sds((EDGES,), i32), sds((EDGES,)),
-            sds((LINEITEM["rows"], LINEITEM["width"])),
+            sds((LINEITEM["rows"], LINEITEM["width"])), sds((2, pairs), i32),
             num_rows=LINEITEM["segments"], interpret=False,
         )
     )
+
+
+def test_coo_spmm_full_grid_compiles(compile_for_tpu):
+    """The long pair list, ``m_tiles * e_tiles`` entries, as many as the
+    full grid's cells, which rows too scattered for the short one take;
+    its custom call carries the same label."""
+    sds, compile_text = compile_for_tpu
+    i32 = jnp.int32
+    cells = -(-LINEITEM["segments"] // 128) * -(-EDGES // 1024)
+    text = compile_text(
+        ops.coo_spmm_pairs.lower(
+            sds((EDGES,), i32), sds((EDGES,), i32), sds((EDGES,)),
+            sds((LINEITEM["rows"], LINEITEM["width"])), sds((2, cells), i32),
+            num_rows=LINEITEM["segments"], interpret=False,
+        )
+    )
+    assert _custom_call_labels(text) == ["coo_spmm"]
 
 
 def test_segment_sum_compiles(compile_for_tpu):
@@ -157,8 +177,9 @@ def _named_calls(sds):
         child_widths=(width,), num_segments=segs,
     ))
     return [
-        ("coo_spmm", ops.coo_spmm,
-         (sds((e,), i32), sds((e,), i32), sds((e,)), sds((rows, width))),
+        ("coo_spmm", ops.coo_spmm_pairs,
+         (sds((e,), i32), sds((e,), i32), sds((e,)), sds((rows, width)),
+          sds((2, ops.pair_grid_len(e, segs)), i32)),
          dict(num_rows=segs)),
         ("segment_sum", ops.segment_sum,
          (sds((e, width)), sds((e,), i32)), dict(num_segments=segs)),
@@ -211,15 +232,19 @@ def _op_label():
     return mod.op_label
 
 
+def _custom_call_labels(text: str) -> list:
+    """The trace labels of the Mosaic custom calls in compiled HLO."""
+    label = _op_label()
+    # an instruction as the trace names it: the HLO line without ROOT
+    return [
+        label(line.strip().removeprefix("ROOT ")) for line in text.splitlines()
+        if re.search(r"custom-call\(", line) and "tpu_custom_call" in line
+    ]
+
+
 @pytest.mark.parametrize("name", KERNELS)
 def test_compiled_custom_call_is_labelled_by_the_kernel_name(compile_for_tpu, name):
     sds, compile_text = compile_for_tpu
     fn, args, kwargs = _call(sds, name)
     text = compile_text(fn.lower(*args, **kwargs, interpret=False))
-    label = _op_label()
-    # an instruction as the trace names it: the HLO line without ROOT
-    calls = [
-        label(line.strip().removeprefix("ROOT ")) for line in text.splitlines()
-        if re.search(r"custom-call\(", line) and "tpu_custom_call" in line
-    ]
-    assert calls == [name]
+    assert _custom_call_labels(text) == [name]
