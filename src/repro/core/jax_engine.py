@@ -742,11 +742,16 @@ class _MinMaxKernelEngine(_CsrHopMixin, TensorEngine):
                     part = ref.segment_reduce_ref(vv, kk, knum, self.kind)
             else:
                 kk, vv, _ = _pad_edges(keys[sl], vals, None)
-                vv, kk = _to_device(vv, np.asarray(kk, np.int32))
+                kk = np.asarray(kk, np.int32)
+                # the kernel's schedule, built from the host's keys and
+                # uploaded with them
+                vv, kk, sched = _to_device(
+                    vv, kk, ops.reduce_schedule(kk, knum)
+                )
                 with obs.span("joinagg.launch"):
                     part = segment_reduce(
                         vv, kk, num_segments=knum, kind=self.kind,
-                        interpret=self.interpret,
+                        schedule=sched, interpret=self.interpret,
                     )
             out = red(out, _to_host(part))
         return out

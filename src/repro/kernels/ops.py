@@ -191,7 +191,11 @@ from repro.kernels.coo_spmm import (  # noqa: E402
     spmm_pair_list,
 )
 from repro.kernels.fused_hop import fused_hop  # noqa: E402
-from repro.kernels.segment_reduce import segment_reduce  # noqa: E402
+from repro.kernels.segment_reduce import (  # noqa: E402
+    reduce_schedule,
+    segment_reduce,
+    segment_reduce_scheduled,
+)
 from repro.kernels.segment_sum import segment_sum  # noqa: E402
 from repro.kernels.semiring_matmul import semiring_matmul  # noqa: E402
 
@@ -207,11 +211,13 @@ __all__ = [
     "normalize_block",
     "pair_grid_len",
     "record_dispatch",
+    "reduce_schedule",
     "reset_dispatch_counts",
     "resolve_interpret",
     "round_up",
     "segment_sum",
     "segment_reduce",
+    "segment_reduce_scheduled",
     "semiring_matmul",
     "spmm_pair_list",
     "use_ref_kernels",

@@ -95,10 +95,14 @@ def _blocks(kernel: str) -> dict[str, int]:
 
 def hop_table(hops: list[dict]) -> list[dict]:
     """The hop kernel calls one query dispatched (``ops.dispatched_hops``)
-    with each call's full grid cells at the blocks it ran with (a
-    ``coo_spmm`` on row-sorted edges runs fewer), and two host
+    with each call's grid cells at the blocks it ran with, and two host
     estimates: the f32 bytes of its child messages and of its
-    ``(segments, width)`` output, before any device padding."""
+    ``(segments, width)`` output, before any device padding.  The cells
+    are those of the full grid, except ``segment_reduce``'s: the length
+    of the schedule that its CSR-sorted keys take (a ``coo_spmm`` on
+    row-sorted edges also runs fewer)."""
+    from repro.kernels import ops
+
     cdiv = lambda a, b: -(-a // b)  # noqa: E731
     rows = []
     for h in hops:
@@ -112,7 +116,9 @@ def hop_table(hops: list[dict]) -> list[dict]:
         elif h["kernel"] == "fused_hop":
             # the wrapper runs a zero-edge hop as one padding tile
             grid = cdiv(segs, b["block_s"]) * max(cdiv(edges, b["block_e"]), 1)
-        else:  # segment_sum / segment_reduce
+        elif h["kernel"] == "segment_reduce":
+            grid = ops.pair_grid_len(edges, segs, b["block_s"], b["block_n"])
+        else:  # segment_sum
             grid = cdiv(segs, b["block_s"]) * cdiv(edges, b["block_n"])
         rows.append(
             {

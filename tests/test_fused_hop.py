@@ -182,7 +182,7 @@ def test_dimension_semantics_declared():
 
     assert coo_spmm.DIM_SEMANTICS == ("arbitrary", "arbitrary")
     assert segment_sum.DIM_SEMANTICS == ("parallel", "arbitrary")
-    assert segment_reduce.DIM_SEMANTICS == ("parallel", "arbitrary")
+    assert segment_reduce.DIM_SEMANTICS == ("arbitrary",)
     assert fused_mod.DIM_SEMANTICS == ("parallel", "arbitrary")
     assert semiring_matmul.DIM_SEMANTICS == ("parallel", "parallel", "arbitrary")
     # the accumulation axis (last grid axis) is sequential in every kernel

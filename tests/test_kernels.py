@@ -188,15 +188,21 @@ def test_semiring_matmul(semiring, m, k, n):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
+def _reduce(data, ids, s, kind, block_s=128, block_n=1024):
+    """``segment_reduce`` in interpret mode with its host ids' schedule."""
+    sched = ops.reduce_schedule(np.asarray(ids), s, block_s, block_n)
+    return segment_reduce(
+        data, ids, num_segments=s, kind=kind, schedule=sched, block_s=block_s,
+        block_n=block_n, interpret=True,
+    )
+
+
 @pytest.mark.parametrize("kind", ["min", "max"])
 @pytest.mark.parametrize("n,d,s", [(100, 8, 16), (2049, 128, 130), (1, 8, 3)])
 def test_segment_reduce(kind, n, d, s):
     data = jnp.asarray(RNG.normal(size=(n, d)), dtype=jnp.float32)
     ids = jnp.asarray(RNG.integers(0, s, size=n), dtype=jnp.int32)
-    got = segment_reduce(
-        data, ids, num_segments=s, kind=kind, block_s=16, block_n=64,
-        interpret=True,
-    )
+    got = _reduce(data, ids, s, kind, block_s=16, block_n=64)
     want = ref.segment_reduce_ref(data, ids, s, kind)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -208,12 +214,133 @@ def test_segment_reduce_block_not_multiple_of_kstep():
     n = 2 * ops.EDGE_GRANULE + 24
     data = jnp.asarray(RNG.normal(size=(n, 4)), dtype=jnp.float32)
     ids = jnp.asarray(RNG.integers(0, 6, size=n), dtype=jnp.int32)
-    got = segment_reduce(
-        data, ids, num_segments=6, kind="min", block_s=8, block_n=12,
-        interpret=True,
-    )
+    got = _reduce(data, ids, 6, "min", block_s=8, block_n=12)
     want = ref.segment_reduce_ref(data, ids, 6, "min")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ----------------------------------------------------------------------
+# segment_reduce's schedule: coo_spmm's pair list at its blocks, and per
+# entry the 8-row windows of the block that hold a key of the tile
+# ----------------------------------------------------------------------
+
+REDUCE_BLOCKS = dict(block_s=16, block_n=1024)
+
+
+def _reduce_sorted():
+    # three full row blocks and a partial fourth, the tail padded with
+    # -1; keys in 0–15, 40–79 and 150–199, so tiles 1, 5–8 hold none
+    keys = np.sort(RNG.choice(np.r_[0:16, 40:80, 150:200], 3300))
+    return np.r_[keys, np.full(3500 - 3300, -1)], 200
+
+
+def _reduce_unsorted():
+    return RNG.integers(-1, 200, 3500), 200
+
+
+REDUCE_CASES = {"sorted": (_reduce_sorted, False), "unsorted": (_reduce_unsorted, True)}
+
+
+@pytest.mark.parametrize("kind", ["min", "max"])
+@pytest.mark.parametrize("d", [1, 25])
+@pytest.mark.parametrize("case", list(REDUCE_CASES))
+def test_segment_reduce_schedule(case, d, kind):
+    """Bit-exact against the reference on the scheduled grid: sorted keys
+    take the short list and reduce only their windows, unsorted ones the
+    long list; tiles no key lands in come back as the identity."""
+    build, full = REDUCE_CASES[case]
+    keys, s = build()
+    data = jnp.asarray(RNG.normal(size=(len(keys), d)), jnp.float32)
+    ids = jnp.asarray(keys, jnp.int32)
+    obs.reset("kernel.")
+    got = np.asarray(_reduce(data, ids, s, kind, **REDUCE_BLOCKS))
+    want = np.asarray(ref.segment_reduce_ref(data, ids, s, kind))
+    np.testing.assert_array_equal(got, want)
+    c = obs.counters()
+    assert c.get("kernel.segment_reduce_full", 0) == (1 if full else 0)
+    assert 0 < c["kernel.segment_reduce_windows"] < (
+        c["kernel.segment_reduce_grid_windows"]
+    )
+    hit = np.zeros(s, bool)
+    hit[keys[keys >= 0]] = True
+    assert np.all(got[~hit] == (np.inf if kind == "min" else -np.inf))
+
+
+def _brute_windows(keys, s, pairs, block_s, block_n):
+    lo, hi = [], []
+    for tile, code in pairs.T:
+        if code < 0:
+            lo.append(0), hi.append(0)
+            continue
+        rows = [
+            r for r in range(code * block_n, min((code + 1) * block_n, len(keys)))
+            if tile * block_s <= keys[r] < min((tile + 1) * block_s, s)
+        ]
+        lo.append(rows[0] % block_n // 8)
+        hi.append(rows[-1] % block_n // 8 + 1)
+    return lo, hi
+
+
+@pytest.mark.parametrize("case", list(REDUCE_CASES))
+def test_row_windows_match_a_scan(case):
+    """Each live entry's windows run from the window of the first row of
+    its block whose key lies in its tile to that of the last."""
+    from repro.kernels.segment_reduce import row_windows
+
+    keys, s = REDUCE_CASES[case][0]()
+    pairs = ops.spmm_pair_list(keys, s, REDUCE_BLOCKS["block_s"], 1024)
+    lo, hi = row_windows(keys, s, pairs, **REDUCE_BLOCKS)
+    want = _brute_windows(keys, s, pairs, REDUCE_BLOCKS["block_s"], 1024)
+    assert (lo.tolist(), hi.tolist()) == want
+    assert np.array_equal(
+        ops.reduce_schedule(keys, s, **REDUCE_BLOCKS), np.stack([*pairs, lo, hi])
+    )
+
+
+@pytest.mark.parametrize("case", list(REDUCE_CASES))
+def test_segment_reduce_counters(case):
+    """The three counters of one call equal what the keys and shapes
+    give: the scheduled windows, ``s_tiles × n_blocks × block_n / 8``,
+    and one long-list call for unsorted keys."""
+    build, full = REDUCE_CASES[case]
+    keys, s = build()
+    bs = REDUCE_BLOCKS["block_s"]
+    cells = {}
+    for r, k in enumerate(keys):
+        if 0 <= k < s:
+            lo, hi = cells.get((k // bs, r // 1024), (r, r))
+            cells[(k // bs, r // 1024)] = (min(lo, r), max(hi, r))
+    windows = sum(hi % 1024 // 8 - lo % 1024 // 8 + 1 for lo, hi in cells.values())
+    obs.reset("kernel.")
+    _reduce(jnp.ones((len(keys), 1)), jnp.asarray(keys, jnp.int32), s, "min",
+            **REDUCE_BLOCKS)
+    want = {
+        "kernel.segment_reduce_windows": windows,
+        "kernel.segment_reduce_grid_windows": -(-s // bs) * 4 * 1024 // 8,
+    }
+    if full:
+        want["kernel.segment_reduce_full"] = 1
+    assert obs.counters() == want
+
+
+@pytest.mark.parametrize(
+    "built_at",
+    [dict(block_s=32, block_n=1024), dict(block_s=16, block_n=2048), None],
+    ids=["block_s", "block_n", "truncated"],
+)
+def test_segment_reduce_refuses_a_schedule_of_the_wrong_length(built_at):
+    """A schedule built at other blocks, or cut short, has neither length
+    the call's shapes allow: the call raises instead of leaving tiles
+    unwritten."""
+    keys, s = _reduce_sorted()
+    sched = ops.reduce_schedule(keys, s, **(built_at or REDUCE_BLOCKS))
+    if built_at is None:
+        sched = sched[:, :-1]
+    with pytest.raises(ValueError, match="schedule"):
+        segment_reduce(
+            jnp.ones((len(keys), 1)), jnp.asarray(keys, jnp.int32), s,
+            schedule=sched, interpret=True, **REDUCE_BLOCKS,
+        )
 
 
 def test_semiring_block_not_multiple_of_kstep():
@@ -230,8 +357,8 @@ def test_segment_reduce_empty_bucket_identity():
     """Buckets no row maps to hold the reduction identity (±inf)."""
     data = jnp.asarray(RNG.normal(size=(4, 8)), dtype=jnp.float32)
     ids = jnp.asarray([0, 0, 2, 2], dtype=jnp.int32)
-    lo = segment_reduce(data, ids, num_segments=4, kind="min", interpret=True)
-    hi = segment_reduce(data, ids, num_segments=4, kind="max", interpret=True)
+    lo = _reduce(data, ids, 4, "min")
+    hi = _reduce(data, ids, 4, "max")
     assert np.all(np.asarray(lo)[1] == np.inf)
     assert np.all(np.asarray(hi)[3] == -np.inf)
 

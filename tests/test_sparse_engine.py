@@ -107,6 +107,28 @@ def test_sparse_hops_run_coo_spmm_on_its_pair_grid():
     assert "kernel.coo_spmm_full" not in c
 
 
+@pytest.mark.parametrize("kind", [Min, Max])
+def test_sparse_minmax_hops_run_segment_reduce_on_its_schedule(kind):
+    """MIN/MAX hops reach ``segment_reduce`` with CSR-sorted keys, so
+    every call takes the short schedule and reduces fewer windows than
+    the full grid holds, and the answer stays exact."""
+    from repro import obs
+
+    db = measured_db(n=2500, b=40)
+    q = JoinAggQuery(
+        ("R1", "R2", "R3"), (("R1", "g1"), ("R3", "g2")), kind("R2", "m")
+    )
+    obs.reset("kernel.")
+    assert_same(
+        execute_jax(q, db, mode="sparse", interpret=True), oracle_joinagg(q, db)
+    )
+    c = obs.counters()
+    assert 0 < c["kernel.segment_reduce_windows"] < (
+        c["kernel.segment_reduce_grid_windows"]
+    )
+    assert "kernel.segment_reduce_full" not in c
+
+
 def test_sparse_matches_dense_bit_identical():
     db, q = CASES["chain"]()
     sparse = execute_jax(q, db, mode="sparse", interpret=True)

@@ -6,8 +6,9 @@ differs from XLA's ``T(1024)``, an in-kernel ``dynamic_slice`` of a
 value, a body that overflows scoped VMEM.  Each test compiles one kernel
 with ``interpret=False`` at 2**16 edges and the segment / child-row
 counts of a hop the chip smoke runs (``repro.launch.smoke`` at
-``chip_smoke.py``'s ``N_SMALL`` = 32,768 lineitem rows), and checks that
-the compiled program calls the Mosaic kernel.
+``chip_smoke.py``'s ``N_SMALL`` = 32,768 lineitem rows), or, for
+``segment_reduce``, at the benchmark's minmax hops, and checks that the
+compiled program calls the Mosaic kernel.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU
@@ -125,15 +126,31 @@ def test_segment_sum_compiles(compile_for_tpu):
     )
 
 
+# the benchmark's tpch_chain.minmax hops, padded to the edge granule:
+# (rows, width, segments) of the customer, orders and lineitem hops
+MINMAX_HOPS = [(3072, 1, 75000), (30720, 25, 30000), (120832, 25, 200)]
+
+
+@pytest.mark.parametrize("long", [False, True], ids=["short", "long"])
 @pytest.mark.parametrize("kind", ["min", "max"])
-def test_segment_reduce_compiles(compile_for_tpu, kind):
+@pytest.mark.parametrize(
+    "rows,width,segs", MINMAX_HOPS, ids=["customer", "orders", "lineitem"]
+)
+def test_segment_reduce_compiles(compile_for_tpu, rows, width, segs, kind, long):
+    """The scheduled grid at the benchmark's hop shapes, with a schedule
+    of either static length: ``s_tiles + n_blocks - 1`` (CSR-sorted keys)
+    or ``s_tiles * n_blocks`` (keys too scattered for it)."""
     sds, compile_text = compile_for_tpu
-    compile_text(
-        ops.segment_reduce.lower(
-            sds((EDGES, LINEITEM["width"])), sds((EDGES,), jnp.int32),
-            num_segments=LINEITEM["segments"], kind=kind, interpret=False,
+    s_tiles, n_blocks = -(-segs // 128), rows // 1024
+    grid_len = s_tiles * n_blocks if long else ops.pair_grid_len(rows, segs)
+    text = compile_text(
+        ops.segment_reduce_scheduled.lower(
+            sds((rows, width)), sds((rows,), jnp.int32),
+            sds((4, grid_len), jnp.int32), num_segments=segs, kind=kind,
+            interpret=False,
         )
     )
+    assert _custom_call_labels(text) == ["segment_reduce"]
 
 
 @pytest.mark.parametrize(
@@ -183,8 +200,10 @@ def _named_calls(sds):
          dict(num_rows=segs)),
         ("segment_sum", ops.segment_sum,
          (sds((e, width)), sds((e,), i32)), dict(num_segments=segs)),
-        ("segment_reduce", ops.segment_reduce,
-         (sds((e, width)), sds((e,), i32)), dict(num_segments=segs, kind="min")),
+        ("segment_reduce", ops.segment_reduce_scheduled,
+         (sds((e, width)), sds((e,), i32),
+          sds((4, ops.pair_grid_len(e, segs)), i32)),
+         dict(num_segments=segs, kind="min")),
         ("fused_hop", ops.fused_hop,
          (sds((e,), i32), sds((e, 1)), (sds((rows, width)),), (sds((e,), i32),)),
          dict(num_segments=segs, k=1, kind="sum", block_e=fused.block_e,
