@@ -5,8 +5,8 @@ every decomposition-tree hop runs device-locally under ``shard_map`` and
 the per-shard group partials are combined with one final all-gather — no
 dense relation tensor is ever built, on any device.
 
-Runs on 8 virtual CPU devices; the same code path lowers onto the
-256/512-chip production meshes in the dry-run.
+Runs on 8 virtual CPU devices; the same code path lowers onto any
+device mesh.
 
     PYTHONPATH=src python examples/distributed_joinagg.py
 """

@@ -25,8 +25,8 @@ running-intersection property — are combined with a final
 (PR 4 retired it on one device, this module retires it on many).
 
 ``run`` executes on whatever devices exist (tests use virtual CPU
-devices); ``lower_distributed`` AOT-lowers the sharded program for the
-multi-pod dry-run.
+devices); ``lower_distributed`` AOT-lowers the sharded program for a
+given mesh.
 """
 from __future__ import annotations
 
@@ -640,5 +640,5 @@ def run_query(prep: Prepared, mesh: Mesh | int) -> dict[tuple, float]:
 
 
 def lower_distributed(prep: Prepared, mesh: Mesh | int):
-    """AOT-lower the sharded sparse COUNT program (multi-pod dry-run)."""
+    """AOT-lower the sharded sparse COUNT program for ``mesh``."""
     return build_distributed_program(prep, (None,), mesh).lower()

@@ -4,10 +4,9 @@ Two physical paths (DESIGN.md §2, §7):
 
 * ``dense``  — every relation becomes a dense multiplicity tensor over its
   relevant attrs; the decomposition-tree contraction lowers to one jitted
-  ``jnp.einsum`` program (MXU path; shardable with NamedSharding — this is
-  what the multi-pod dry-run lowers).  Fast at small domains, but the
-  per-relation ``Π|dom(attrs)|`` tensors are the exact intermediate
-  blowup JOIN-AGG exists to avoid.
+  ``jnp.einsum`` program (MXU path; shardable with NamedSharding).  Fast
+  at small domains, but the per-relation ``Π|dom(attrs)|`` tensors are
+  the exact intermediate blowup JOIN-AGG exists to avoid.
 * ``sparse`` — relations stay in grouped-CSR coordinate form
   (:class:`~repro.core.prepare.CSRView`) and every decomposition-tree hop
   runs on the Pallas kernels: ``coo_spmm`` for single-child hops,

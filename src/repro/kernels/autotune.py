@@ -5,9 +5,9 @@ The fused megakernel's throughput is set by its tile sizes: ``block_e``
 gather tile).  This module picks them per device:
 
 * **model ranking** — every candidate config is scored with
-  :func:`repro.launch.roofline.fused_hop_cost` (the two-term
+  :func:`repro.kernels.fused_hop.fused_hop_cost` (the two-term
   flops/bytes roofline) after filtering configs whose per-cell VMEM
-  footprint exceeds :data:`repro.launch.roofline.VMEM_BYTES`.  Ranking
+  footprint exceeds :data:`repro.kernels.fused_hop.VMEM_BYTES`.  Ranking
   is deterministic, so ``Plan.explain()`` and the plan goldens use it
   directly (:func:`model_tiles_for` — never the disk cache).
 * **measurement** — on a real accelerator, the top
@@ -32,6 +32,8 @@ from dataclasses import dataclass
 from pathlib import Path
 
 import jax
+
+from repro.kernels import fused_hop
 
 __all__ = [
     "HopShape",
@@ -95,6 +97,10 @@ _BLOCK_R = (128, 256)
 #: how many model-ranked candidates get measured on a real accelerator
 MEASURE_TOP_N = 3
 
+#: the table's home unless ``REPRO_AUTOTUNE_CACHE`` says otherwise: the
+#: checkout's git-ignored ``.cache``, beside JAX's compile cache
+_DEFAULT_CACHE = Path(__file__).resolve().parents[3] / ".cache" / "autotune.json"
+
 _lock = threading.Lock()
 _memory_cache: dict[str, TileConfig] = {}
 _disk_loaded = False
@@ -145,17 +151,15 @@ def device_kind() -> str:
 def candidate_tiles(shape: HopShape) -> list[tuple[float, TileConfig]]:  # tile-math
     """VMEM-admissible candidates ranked by modeled seconds (ascending);
     ties break on the config key so the order is fully deterministic."""
-    from repro.launch import roofline
-
     segments = shape.num_segments or 8
     ranked: list[tuple[float, TileConfig]] = []
     for be in _BLOCK_E:
         for bs in _BLOCK_S:
             for br in _BLOCK_R:
                 cfg = TileConfig(be, bs, br)
-                if _vmem_bytes(shape, cfg) > roofline.VMEM_BYTES:
+                if _vmem_bytes(shape, cfg) > fused_hop.VMEM_BYTES:
                     continue
-                cost = roofline.fused_hop_cost(
+                cost = fused_hop.fused_hop_cost(
                     edges=shape.edges,
                     child_rows=shape.child_rows,
                     child_widths=shape.child_widths,
@@ -171,9 +175,7 @@ def candidate_tiles(shape: HopShape) -> list[tuple[float, TileConfig]]:  # tile-
 
 
 def _vmem_bytes(shape: HopShape, cfg: TileConfig) -> int:
-    from repro.launch import roofline
-
-    return roofline.fused_hop_vmem_bytes(
+    return fused_hop.fused_hop_vmem_bytes(
         cfg.block_e,
         cfg.block_s,
         cfg.block_r,
@@ -188,8 +190,6 @@ def _vmem_bytes(shape: HopShape, cfg: TileConfig) -> int:
 def no_fit_reason(shape: HopShape) -> str:
     """Why no candidate tile fits: the hop shape, the least VMEM any
     candidate needs, and the bound."""
-    from repro.launch import roofline
-
     least = min(
         _vmem_bytes(shape, TileConfig(be, bs, br))
         for be in _BLOCK_E
@@ -198,7 +198,7 @@ def no_fit_reason(shape: HopShape) -> str:
     )
     return (
         f"no fused-hop tile fits VMEM for hop {shape.key()}: the smallest "
-        f"candidate needs {least} B > bound {roofline.VMEM_BYTES} B "
+        f"candidate needs {least} B > bound {fused_hop.VMEM_BYTES} B "
         "(whole child messages stay resident in VMEM)"
     )
 
@@ -222,9 +222,7 @@ def _cache_path() -> Path:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return Path(env)
-    from repro.launch.cache import CACHE_DIR
-
-    return CACHE_DIR / "autotune.json"
+    return _DEFAULT_CACHE
 
 
 def _load_disk_cache() -> None:

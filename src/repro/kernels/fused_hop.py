@@ -364,3 +364,117 @@ def fused_hop(
         name="fused_hop",  # the custom call's name in HLO and device traces
     )(keys, weights, *idxs, *msgs, *(jnp.asarray(m) for m in exp_mats))
     return out[:num_segments]
+
+
+# ----------------------------------------------------------------------
+# VMEM and cost model (DESIGN.md §13): the autotuner keeps candidate
+# tiles within VMEM_BYTES and ranks them by fused_hop_cost
+# ----------------------------------------------------------------------
+
+#: v5e peak bf16 FLOP/s and HBM bytes/s, the two terms of fused_hop_cost
+PEAK_FLOPS = 197e12
+HBM_BW = 819e9
+
+#: per-core VMEM budget the autotuner keeps a fused-hop cell within
+VMEM_BYTES = 16 * 2**20
+
+
+def _vmem_tile(rows: int, cols: int) -> int:  # tile-math
+    """Bytes of a 32-bit ``(rows, cols)`` array in VMEM, padded to the
+    ``(8, 128)`` sublane × lane tiling."""
+    return 4 * (-(-max(rows, 1) // 8) * 8) * (-(-max(cols, 1) // 128) * 128)
+
+
+def fused_hop_vmem_bytes(  # tile-math
+    block_e: int,
+    block_s: int,
+    block_r: int,
+    child_rows: tuple[int, ...],
+    child_widths: tuple[int, ...],
+    width: int,
+    k: int,
+    kind: str = "sum",
+) -> int:
+    """Bytes of VMEM one fused-hop grid cell holds, padded to the
+    ``(8, 128)`` tiling.
+
+    Pipelined windows count twice (double-buffered): the edge tile's
+    1-D key/index columns and ``(block_e, k)`` weights, and the output
+    tile.  Whole-array windows — the child messages and the product's
+    expansion matrices — are single-buffered and count once.  Body
+    temporaries count once, at the kernel's ``SUB_E``-edge sub-chunk:
+    the gather selector and its iota, each child's gathered tile (twice
+    for min/max, which carries a finiteness mask), the widened product
+    operands, the scatter selector and its iota; for min/max add the
+    whole-tile candidate and key-column scratch plus one
+    ``(block_s, 8, width)`` window.  Checked against what the TPU
+    compiler allocates for a described v5e: on the tested shapes it
+    over-counts, never under-counts."""
+    rows_pad = [max(-(-r // block_r) * block_r, block_r) for r in child_rows]
+    out_w = max(width * k, 1)
+    piped = 4 * block_e * (1 + len(child_rows)) + _vmem_tile(block_e, k)
+    piped += _vmem_tile(block_s, out_w)
+    resident = sum(
+        _vmem_tile(r, wc * k) for r, wc in zip(rows_pad, child_widths)
+    )
+    wa = 1
+    for wc in child_widths:  # expansion matrices (see fused_hop)
+        if wc > 1 and wa * k > 1:
+            resident += _vmem_tile(wa * k, wa * wc * k)
+        if wa > 1:
+            resident += _vmem_tile(wc * k, wa * wc * k)
+        wa *= wc
+    minmax = kind != "sum"
+    sub_e = min(block_e, SUB_E)
+    temps = 2 * _vmem_tile(sub_e, block_r) + 2 * _vmem_tile(block_s, sub_e)
+    temps += sum(
+        (2 if minmax else 1) * _vmem_tile(sub_e, wc * k)
+        for wc in child_widths
+    )
+    temps += 3 * _vmem_tile(sub_e, out_w)
+    if minmax:
+        temps += _vmem_tile(block_e, out_w) + _vmem_tile(block_e, 1)
+        temps += 2 * block_s * _vmem_tile(8, out_w)
+    return 2 * piped + resident + temps
+
+
+def fused_hop_cost(  # tile-math
+    edges: int,
+    child_rows: tuple[int, ...],
+    child_widths: tuple[int, ...],
+    num_segments: int,
+    k: int = 1,
+    block_e: int = 1024,
+    block_s: int = 128,
+    block_r: int = 128,
+) -> dict[str, float]:
+    """Roofline estimate for one fused hop at the given tile config.
+
+    FLOPs per grid cell: the one-hot gather matmuls
+    (``2·block_e·rows_pad_c·width_c·k`` per child — the selector dot
+    spans every padded child row) plus the segment scatter
+    (``2·block_s·block_e·width·k``).  Cells = s_tiles × e_tiles.  HBM
+    bytes: the edge arrays and child messages are re-read once per
+    segment tile (the output tile is resident, the inputs stream), the
+    output is written once.  Seconds = max(flops/PEAK_FLOPS,
+    bytes/HBM_BW) — the standard two-term roofline.
+    """
+    width = 1
+    for wc in child_widths:
+        width *= wc
+    e_tiles = max(-(-edges // block_e), 1)
+    s_tiles = max(-(-num_segments // block_s), 1)
+    rows_pad = [max(-(-r // block_r) * block_r, block_r) for r in child_rows]
+    gather_flops = sum(
+        2.0 * block_e * rp * wc * k for rp, wc in zip(rows_pad, child_widths)
+    )
+    scatter_flops = 2.0 * block_s * block_e * width * k
+    flops = (gather_flops + scatter_flops) * e_tiles * s_tiles
+
+    edge_bytes = 4.0 * block_e * e_tiles * (2 + len(child_rows) + k)
+    msg_bytes = 4.0 * sum(
+        rp * wc * k for rp, wc in zip(rows_pad, child_widths)
+    )
+    hbm = (edge_bytes + msg_bytes) * s_tiles + 4.0 * s_tiles * block_s * width * k
+    seconds = max(flops / PEAK_FLOPS, hbm / HBM_BW)
+    return {"flops": flops, "hbm_bytes": hbm, "seconds": seconds}

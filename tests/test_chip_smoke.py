@@ -53,8 +53,7 @@ def test_fused_reach_on_the_tpch_chain():
     and refuses even k=1 one size above N_SMALL.  The refusal comes from
     the resident message, not the edge tile: a 256-edge tile would not
     fit either."""
-    from repro.kernels import autotune
-    from repro.launch import roofline
+    from repro.kernels import autotune, fused_hop
 
     n_small = _chip_smoke_module().N_SMALL
 
@@ -71,16 +70,16 @@ def test_fused_reach_on_the_tpch_chain():
     for rel in refused:
         shape = k5[rel]["shape"]
         assert shape.child_widths == (161,)
-        assert f"bound {roofline.VMEM_BYTES} B" in k5[rel]["no_fit"]
+        assert f"bound {fused_hop.VMEM_BYTES} B" in k5[rel]["no_fit"]
         # a 256-edge tile (below the 1024 edge granule) would not fit either
         least = min(
-            roofline.fused_hop_vmem_bytes(
+            fused_hop.fused_hop_vmem_bytes(
                 256, bs, br, shape.child_rows, shape.child_widths,
                 shape.width, 5,
             )
             for bs in (64, 128, 256) for br in (128, 256)
         )
-        assert least > roofline.VMEM_BYTES
+        assert least > fused_hop.VMEM_BYTES
         with pytest.raises(ValueError, match=r"fused_hop\|.*\|k5\|sum"):
             autotune.model_tiles_for(shape)
     assert configs(2 * n_small, 1)["lineitem"]["config"] is None

@@ -442,14 +442,13 @@ def test_no_fitting_tile_raises_to_the_client(monkeypatch, tmp_path):
     the verifier report it, and the error reaches a served client."""
     from repro.analysis.verify import check_kernels
     from repro.api import Q
-    from repro.kernels import autotune
-    from repro.launch import roofline
+    from repro.kernels import autotune, fused_hop as fused_mod
     from repro.relational.relation import Database
     from repro.serve.server import JoinAggServer
     from repro.serve.session import Session
 
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "tiles.json"))
-    monkeypatch.setattr(roofline, "VMEM_BYTES", 1 << 16)
+    monkeypatch.setattr(fused_mod, "VMEM_BYTES", 1 << 16)
     autotune.reset_cache()
     try:
         db = Database.from_mapping(_star_db(n=120))
