@@ -23,6 +23,7 @@ against silent overflow by checking the f64 numpy result in tests.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -370,24 +371,69 @@ EDGE_BUCKET = EDGE_GRANULE
 _INT32_LIMIT = 2**31
 
 
-def _pad_edges(keys: np.ndarray, vals: np.ndarray, idx: np.ndarray | None):
+def _pad_edges(keys: np.ndarray, *cols: np.ndarray) -> list[np.ndarray]:
     """Pad an edge block to the bucket size: key -1 rows are dropped by
-    the kernels, value rows are zero."""
+    the kernels, the rows of every other column (weights, gather
+    indices) are zero."""
     pad = -len(keys) % EDGE_BUCKET
     if pad == 0:
-        return keys, vals, idx
-    keys = np.concatenate([keys, np.full(pad, -1, np.int64)])
-    vals = np.concatenate(
-        [vals, np.zeros((pad,) + vals.shape[1:], vals.dtype)]
+        return [keys, *cols]
+    return [np.concatenate([keys, np.full(pad, -1, keys.dtype)])] + [
+        np.concatenate([c, np.zeros((pad,) + c.shape[1:], c.dtype)])
+        for c in cols
+    ]
+
+
+def _int32_indexable(knum: int, gathers) -> bool:
+    """The hop's row keys and its gather indices into every child
+    message, all channels stacked, fit the kernels' int32."""
+    return knum < _INT32_LIMIT and all(
+        m2.shape[0] * int(np.prod(m2.shape[2:], dtype=np.int64)) < _INT32_LIMIT
+        for m2, _ in gathers
     )
-    if idx is not None:
-        idx = np.concatenate([idx, np.zeros(pad, np.int64)])
-    return keys, vals, idx
 
 
-# the ref spmm's per-edge gather materializes (edges × width); chunk the
-# edge axis so the intermediate stays within this bound (the Pallas
-# kernel streams the same product through VMEM blocks instead)
+@functools.partial(jax.jit, static_argnames=("shapes", "k", "op"))
+def hop_gather(keys, w, msgs, idxs, *, shapes, k, op):
+    """One edge chunk's per-edge product, formed on the device: each
+    edge's weight combined with every child's message row at that edge's
+    gather index (``*`` for COUNT/SUM, ``+`` for the (min, +) / (max, +)
+    semirings), outer across the children's group axes, one channel at a
+    time.  The weight comes first, then each child in decomposition
+    order, as in the numpy engine, so results stay bit-identical to it
+    (IEEE products and sums do not reassociate).
+
+    Every array is channel-major and uploaded 1-D (such an upload takes
+    no layout transpose): ``w`` holds the chunk's ``k`` weight columns
+    one after another, each of ``msgs`` a child's ``(k, rows, width)``
+    message raveled, ``shapes`` their ``(rows, width)``; ``idxs`` are the
+    int32 gather indices and ``keys`` the chunk's padded int32 row keys.
+    Channel-major, the product never puts the short channel axis minor,
+    which keeps the program a second's compile.  Rows of the pad (key
+    -1) come out 0, as a host pad made them, so a pad row that reads an
+    inf makes no NaN for a one-hot matmul to spread.  Returns ``(n, k ·
+    Π width)``, channel-major, which the engine hands to ``segment_sum``
+    / ``segment_reduce`` on the device."""
+    n = keys.shape[0]
+    combine = jnp.multiply if op == "mul" else jnp.add
+    chans = []
+    for c in range(k):
+        vals, p = w[c * n:(c + 1) * n].reshape(n, 1), 1  # p: width so far
+        for flat, idx, (rows, width) in zip(msgs, idxs, shapes):
+            got = flat.reshape(k * rows, width)[idx + c * rows]
+            vals = combine(vals[:, :, None], got[:, None, :]).reshape(
+                n, p * width
+            )
+            p *= width
+        chans.append(vals)
+    vals = chans[0] if k == 1 else jnp.concatenate(chans, axis=1)
+    return jnp.where(keys[:, None] >= 0, vals, 0)
+
+
+# the ref spmm's per-edge gather and hop_gather's product materialize
+# (edges × width) in HBM; chunk the edge axis so the intermediate stays
+# within this bound (the Pallas coo_spmm streams its product through
+# VMEM blocks instead)
 _REF_GATHER_BYTES = 64 << 20
 
 
@@ -519,6 +565,43 @@ class _CsrHopMixin:
             )
         return _to_host(out)
 
+    def _device_products(self, w32, gathers, keys, op, k=1):
+        """Yield, per edge chunk of one hop, ``(vals, keys_d, keys)``:
+        the chunk's per-edge product formed on the device by
+        :func:`hop_gather` (channel-major), and its padded int32 keys on
+        the device and on the host.  Up go each child message once per
+        hop, then per chunk only its keys, weights and gather indices;
+        the product lives only in HBM.  The edge axis is chunked so that
+        product stays bounded by ``_REF_GATHER_BYTES`` instead of growing
+        with the relation."""
+        from repro.kernels import ops
+
+        shapes = tuple(m2.shape[:2] for m2, _ in gathers)
+        width = int(np.prod([w for _, w in shapes], dtype=np.int64))
+        msgs = tuple(_to_device(*(
+            np.ascontiguousarray(
+                np.moveaxis(m2.reshape(*m2.shape[:2], k), 2, 0), np.float32
+            ).reshape(-1)
+            for m2, _ in gathers
+        ))) if gathers else ()
+        chunk = max(1024, _REF_GATHER_BYTES // max(4 * width * k, 1))
+        for lo in range(0, len(keys), chunk):
+            sl = slice(lo, lo + chunk)
+            kk, ww, *ii = _pad_edges(
+                np.asarray(keys[sl], np.int32), w32[sl].reshape(-1, k),
+                *(np.asarray(idx[sl], np.int32) for _, idx in gathers),
+            )
+            kk_d, ww_d, *ii_d = _to_device(kk, ww.T.reshape(-1), *ii)
+            if gathers:
+                ops.record_dispatch("gather", len(gathers))
+                ops.record_dispatch("product", len(gathers))
+            obs.count("hop.product_edges_device", len(w32[sl]))
+            with obs.span("joinagg.launch"):
+                vals = hop_gather(
+                    kk_d, ww_d, msgs, tuple(ii_d), shapes=shapes, k=k, op=op
+                )
+            yield vals, kk_d, kk
+
 
 class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
     """k-channel contraction whose gather-product-scatter hot loop runs
@@ -529,7 +612,9 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
       the operand's column dimension (``(rows, width·k)``).
     * leaf / multi-child / measure-weighted hop → the per-edge
       channel-diagonal product of gathered child message rows is formed
-      host-side and reduced with ``segment_sum``.
+      on the device (:func:`hop_gather`) and reduced there with
+      ``segment_sum``; the host uploads only the child messages, gather
+      indices, weights and keys.
     """
 
     def __init__(
@@ -563,16 +648,14 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
         from repro.kernels.ops import coo_spmm, segment_sum
 
         n = len(weights)
-        if n == 0 or knum >= _INT32_LIMIT:
+        if n == 0 or not _int32_indexable(knum, gathers):
+            if n:  # beyond the kernels' int32: the numpy engine's product
+                obs.count("hop.product_edges_host", n)
             out = super()._contract_block(weights, gathers, keys, knum)
             return out.astype(np.float32)
         w32 = np.asarray(weights, dtype=np.float32)  # (n, k)
-        if ops.fused_enabled(self.fused) and all(
-            m2.shape[0] < _INT32_LIMIT for m2, _ in gathers
-        ):
-            width = 1
-            for m2, _ in gathers:
-                width *= m2.shape[1]
+        width = int(np.prod([m2.shape[1] for m2, _ in gathers], dtype=np.int64))
+        if ops.fused_enabled(self.fused):
             out = self._fused_contract(
                 w32, gathers, keys, knum, "sum", k=self.k
             )
@@ -580,87 +663,67 @@ class _KernelChannelEngine(_CsrHopMixin, ChannelTensorEngine):
         use_ref = ops.use_ref_kernels(self.interpret)
         if len(gathers) == 1 and self._uniform(w32):
             m2, idx = gathers[0]  # m2 (rows, width, k)
-            rows, width = m2.shape[0], m2.shape[1]
-            if rows < _INT32_LIMIT:
-                flat = np.ascontiguousarray(m2, dtype=np.float32).reshape(
-                    rows, width * self.k
-                )
-                ops.record_dispatch(
-                    "spmm",
-                    hop=dict(
-                        kernel="coo_spmm", edges=ops.round_up(n, EDGE_BUCKET),
-                        segments=knum, child_rows=rows, width=width * self.k,
-                    ),
-                )
-                if use_ref:
-                    out = _ref_spmm_chunked(keys, idx, w32[:, 0], flat, knum)
-                else:
-                    kk, vv, ii = _pad_edges(keys, w32[:, 0], idx)
-                    kk = np.asarray(kk, np.int32)
-                    # the kernel's schedule, built from the host's keys and
-                    # uploaded with them
-                    *args, pairs = _to_device(
-                        kk, np.asarray(ii, np.int32), vv, flat,
-                        ops.spmm_pair_list(kk, knum),
-                    )
-                    with obs.span("joinagg.launch"):
-                        out = coo_spmm(
-                            *args, num_rows=knum, pairs=pairs,
-                            interpret=self.interpret,
-                        )
-                    out = _to_host(out)
-                return out.reshape(knum, width, self.k)
-        # general hop: row-aligned product of gathered child rows, then a
-        # device-side segment reduction into the CSR row keys; the edge
-        # axis is chunked so the per-edge product temp stays bounded by
-        # _REF_GATHER_BYTES instead of growing with the relation
-        width = 1
-        g32 = []
-        for m2, idx in gathers:
-            width *= m2.shape[1]
-            g32.append((np.asarray(m2, np.float32), idx))
-        chunk = max(1024, _REF_GATHER_BYTES // max(4 * width * self.k, 1))
-        out = np.zeros((knum, width, self.k), np.float32)
-        for lo in range(0, n, chunk):
-            sl = slice(lo, lo + chunk)
-            vals = w32[sl].reshape(-1, 1, self.k)
-            for m2, idx in g32:
-                ops.record_dispatch("gather")
-                rows = m2[idx[sl]]  # (c, Wc, k)
-                ops.record_dispatch("product")
-                vals = (vals[:, :, None, :] * rows[:, None, :, :]).reshape(
-                    vals.shape[0], -1, self.k
-                )
-            flat = vals.reshape(vals.shape[0], width * self.k)
+            rows = m2.shape[0]
+            flat = np.ascontiguousarray(m2, dtype=np.float32).reshape(
+                rows, width * self.k
+            )
             ops.record_dispatch(
-                "scatter",
+                "spmm",
                 hop=dict(
-                    kernel="segment_sum",
-                    edges=ops.round_up(len(flat), EDGE_BUCKET), segments=knum,
-                    child_rows=sum(m2.shape[0] for m2, _ in g32),
-                    width=width * self.k,
+                    kernel="coo_spmm", edges=ops.round_up(n, EDGE_BUCKET),
+                    segments=knum, child_rows=rows, width=width * self.k,
                 ),
             )
             if use_ref:
-                vv, kk = _to_device(flat, np.asarray(keys[sl], np.int32))
-                with obs.span("joinagg.launch"):
-                    part = ref.segment_sum_ref(vv, kk, knum)
+                out = _ref_spmm_chunked(keys, idx, w32[:, 0], flat, knum)
             else:
-                kk, vv, _ = _pad_edges(keys[sl], flat, None)
-                vv, kk = _to_device(vv, np.asarray(kk, np.int32))
+                kk, vv, ii = _pad_edges(keys, w32[:, 0], idx)
+                kk = np.asarray(kk, np.int32)
+                # the kernel's schedule, built from the host's keys and
+                # uploaded with them
+                *args, pairs = _to_device(
+                    kk, np.asarray(ii, np.int32), vv, flat,
+                    ops.spmm_pair_list(kk, knum),
+                )
                 with obs.span("joinagg.launch"):
-                    part = segment_sum(
-                        vv, kk, num_segments=knum, interpret=self.interpret
+                    out = coo_spmm(
+                        *args, num_rows=knum, pairs=pairs,
+                        interpret=self.interpret,
                     )
-            out += _to_host(part).reshape(knum, width, self.k)
-        return out
+                out = _to_host(out)
+            return out.reshape(knum, width, self.k)
+        # general hop: the row-aligned product of gathered child rows,
+        # formed on the device and reduced there into the CSR row keys;
+        # it comes back channel-major
+        out = np.zeros((knum, self.k * width), np.float32)
+        for vals, kk_d, kk in self._device_products(
+            w32, gathers, keys, "mul", self.k
+        ):
+            ops.record_dispatch(
+                "scatter",
+                hop=dict(
+                    kernel="segment_sum", edges=len(kk), segments=knum,
+                    child_rows=sum(m2.shape[0] for m2, _ in gathers),
+                    width=width * self.k,
+                ),
+            )
+            with obs.span("joinagg.launch"):
+                if use_ref:
+                    part = ref.segment_sum_ref(vals, kk_d, knum)
+                else:
+                    part = segment_sum(
+                        vals, kk_d, num_segments=knum, interpret=self.interpret
+                    )
+            out += _to_host(part)
+        return out.reshape(knum, self.k, width).transpose(0, 2, 1)
 
 
 class _MinMaxKernelEngine(_CsrHopMixin, TensorEngine):
     """(min, +) / (max, +) semiring message passing over the tree: the
     measure relation contributes its per-edge payload, every other
-    relation contributes 0, and each hop reduces the per-edge candidate
-    sums into their row keys with the Pallas ``segment_reduce`` kernel.
+    relation contributes 0, and each hop forms its per-edge candidate
+    sums on the device (:func:`hop_gather`) and reduces them into their
+    row keys there with the Pallas ``segment_reduce`` kernel.
     Unreached entries hold the identity (±inf) until :meth:`run` masks
     them.  Min/max ignore multiplicities, so no re-rooting at the
     measure relation is needed (unlike the reachability kernel)."""
@@ -694,62 +757,48 @@ class _MinMaxKernelEngine(_CsrHopMixin, TensorEngine):
         from repro.kernels.ops import segment_reduce
 
         n = len(weights)
-        width = 1
-        g32 = []
-        for m2, idx in gathers:
-            width *= m2.shape[1]
-            g32.append((np.asarray(m2, np.float32), idx))
+        width = int(np.prod([m2.shape[1] for m2, _ in gathers], dtype=np.int64))
         red = np.minimum if self.kind == "min" else np.maximum
         out = np.full((knum, width), self.ident, np.float32)
         if n == 0:
             return out
         w32 = np.asarray(weights, np.float32)
-        if (
-            ops.fused_enabled(self.fused)
-            and knum < _INT32_LIMIT
-            and all(m2.shape[0] < _INT32_LIMIT for m2, _ in g32)
-        ):
-            return self._fused_contract(w32, g32, keys, knum, self.kind)
+        if not _int32_indexable(knum, gathers):
+            # beyond the kernels' int32: the one product formed on the
+            # host, its edge axis chunked like the device's
+            obs.count("hop.product_edges_host", n)
+            chunk = max(1024, _REF_GATHER_BYTES // max(4 * width, 1))
+            for lo in range(0, n, chunk):
+                sl = slice(lo, lo + chunk)
+                vals = w32[sl].reshape(-1, 1)
+                for m2, idx in gathers:
+                    rows = np.asarray(m2, np.float32)[idx[sl]]  # (c, Wc)
+                    vals = (vals[:, :, None] + rows[:, None, :]).reshape(
+                        vals.shape[0], -1
+                    )
+                red.at(out, keys[sl], vals)
+            return out
+        if ops.fused_enabled(self.fused):
+            return self._fused_contract(w32, gathers, keys, knum, self.kind)
         use_ref = ops.use_ref_kernels(self.interpret)
-        # edge axis chunked like the channel engine's general hop: the
-        # per-edge candidate temp stays bounded by _REF_GATHER_BYTES
-        chunk = max(1024, _REF_GATHER_BYTES // max(4 * width, 1))
-        for lo in range(0, n, chunk):
-            sl = slice(lo, lo + chunk)
-            vals = w32[sl].reshape(-1, 1)
-            for m2, idx in g32:
-                ops.record_dispatch("gather")
-                rows = m2[idx[sl]]  # (c, Wc)
-                ops.record_dispatch("product")
-                vals = (vals[:, :, None] + rows[:, None, :]).reshape(
-                    vals.shape[0], -1
-                )
+        for vals, kk_d, kk in self._device_products(w32, gathers, keys, "add"):
             ops.record_dispatch(
                 "scatter",
                 hop=dict(
-                    kernel="segment_reduce",
-                    edges=ops.round_up(len(vals), EDGE_BUCKET), segments=knum,
-                    child_rows=sum(m2.shape[0] for m2, _ in g32), width=width,
+                    kernel="segment_reduce", edges=len(kk), segments=knum,
+                    child_rows=sum(m2.shape[0] for m2, _ in gathers),
+                    width=width,
                 ),
             )
-            if knum >= _INT32_LIMIT:
-                red.at(out, keys[sl], vals)
-                continue
             if use_ref:
-                vv, kk = _to_device(vals, np.asarray(keys[sl], np.int32))
                 with obs.span("joinagg.launch"):
-                    part = ref.segment_reduce_ref(vv, kk, knum, self.kind)
+                    part = ref.segment_reduce_ref(vals, kk_d, knum, self.kind)
             else:
-                kk, vv, _ = _pad_edges(keys[sl], vals, None)
-                kk = np.asarray(kk, np.int32)
-                # the kernel's schedule, built from the host's keys and
-                # uploaded with them
-                vv, kk, sched = _to_device(
-                    vv, kk, ops.reduce_schedule(kk, knum)
-                )
+                # the kernel's schedule, built from the host's keys
+                (sched,) = _to_device(ops.reduce_schedule(kk, knum))
                 with obs.span("joinagg.launch"):
                     part = segment_reduce(
-                        vv, kk, num_segments=knum, kind=self.kind,
+                        vals, kk_d, num_segments=knum, kind=self.kind,
                         schedule=sched, interpret=self.interpret,
                     )
             out = red(out, _to_host(part))

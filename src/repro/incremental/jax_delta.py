@@ -30,22 +30,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core.jax_engine import EDGE_BUCKET
+from repro.core.jax_engine import _pad_edges
 from repro.core.tensor_engine import TensorEngine
-
-
-def _pad_block(
-    keys: np.ndarray, weights: np.ndarray, idx: np.ndarray | None
-) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
-    pad = -len(keys) % EDGE_BUCKET
-    if pad == 0:
-        return keys, weights, idx
-    # key -1 / val 0 rows are dropped by both kernels
-    keys = np.concatenate([keys, np.full(pad, -1, np.int64)])
-    weights = np.concatenate([weights, np.zeros(pad, weights.dtype)])
-    if idx is not None:
-        idx = np.concatenate([idx, np.zeros(pad, np.int64)])
-    return keys, weights, idx
 
 
 class KernelDeltaEngine(TensorEngine):
@@ -77,7 +63,7 @@ class KernelDeltaEngine(TensorEngine):
         w32 = np.asarray(weights, dtype=np.float32)
         if len(gathers) == 1:
             m2, idx = gathers[0]
-            k, w, idx = _pad_block(keys, w32, idx)
+            k, w, idx = _pad_edges(keys, w32, idx)
             out = coo_spmm(
                 jnp.asarray(k), jnp.asarray(idx), jnp.asarray(w),
                 jnp.asarray(m2, dtype=jnp.float32), num_rows=knum,
@@ -88,12 +74,7 @@ class KernelDeltaEngine(TensorEngine):
             for m2, idx in gathers:
                 rows = m2[idx].astype(np.float32)
                 vals = (vals[:, :, None] * rows[:, None, :]).reshape(n, -1)
-            k, _, _ = _pad_block(keys, w32, None)
-            pad = len(k) - n
-            if pad:
-                vals = np.concatenate(
-                    [vals, np.zeros((pad, vals.shape[1]), np.float32)]
-                )
+            k, vals = _pad_edges(keys, vals)
             out = segment_sum(
                 jnp.asarray(vals), jnp.asarray(k), num_segments=knum,
                 interpret=self.interpret,

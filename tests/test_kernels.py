@@ -311,7 +311,7 @@ def test_segment_reduce_counters(case):
             lo, hi = cells.get((k // bs, r // 1024), (r, r))
             cells[(k // bs, r // 1024)] = (min(lo, r), max(hi, r))
     windows = sum(hi % 1024 // 8 - lo % 1024 // 8 + 1 for lo, hi in cells.values())
-    obs.reset("kernel.")
+    obs.reset()
     _reduce(jnp.ones((len(keys), 1)), jnp.asarray(keys, jnp.int32), s, "min",
             **REDUCE_BLOCKS)
     want = {

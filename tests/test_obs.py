@@ -200,3 +200,35 @@ def test_sparse_query_dispatches_one_hop_kernel_per_relation(db):
     assert len(hops) == 4
     assert counts.get("spmm", 0) + counts.get("scatter", 0) == 4
     assert counts.get("spmm", 0) == sum(h["kernel"] == "coo_spmm" for h in hops)
+
+
+def test_every_general_and_minmax_hop_forms_its_product_on_the_device(db):
+    """``hop.product_edges_device`` counts the edges of every hop that
+    forms a per-edge product (the COUNT + SUM walk's leaf and
+    channel-weighted hops, every hop of a MIN or MAX walk);
+    ``hop.product_edges_host`` counts none."""
+    from repro.core.prepare import prepare
+    from repro.core.query import JoinAggQuery
+
+    q = JoinAggQuery(
+        ("supplier", "lineitem", "orders", "customer"),
+        (("supplier", "sname"), ("customer", "zipcode")),
+        Sum("lineitem", "qty"),
+    )
+    prep = prepare(q, db)
+    prog = jax_engine.build_sparse_program(prep, (None, "lineitem"))
+    eng = jax_engine._KernelChannelEngine(
+        prep, 2, jax_engine.channel_weight_matrices(prep.encoded, (None, "lineitem"))
+    )
+    edges = {rel: er.num_rows for rel, er in prep.encoded.items()}
+    general = [rel for rel in edges if eng._hop_kernel(rel) == "segment_sum"]
+    assert "lineitem" in general and len(general) < len(edges)
+    obs.reset("hop.")
+    prog.run_channels()
+    prog.run_minmax("min", "lineitem")
+    prog.run_minmax("max", "lineitem")
+    c = obs.counters()
+    assert c["hop.product_edges_device"] == (
+        sum(edges[r] for r in general) + 2 * sum(edges.values())
+    )
+    assert "hop.product_edges_host" not in c

@@ -285,3 +285,145 @@ def test_sparse_program_stream_tiles_cover_domain():
         tiles += 1
     assert tiles >= 2
     np.testing.assert_array_equal(tiled, full)
+
+
+# ----------------------------------------------------------------------
+# the general and MIN/MAX hops form their per-edge product on the device
+# ----------------------------------------------------------------------
+
+# edge counts just off the 1,024-edge bucket, so every hop has a pad tail
+EDGE_COUNTS = [1023, 1025, 2049]
+# both device paths: Pallas in interpret mode, and the jnp reference kernels
+INTERPRET = [True, None]
+
+
+def _engines(interpret):
+    """A k = 2 channel engine and a MIN and a MAX engine; the blocks under
+    test take their shapes from their arguments, not from this plan."""
+    from repro.core.jax_engine import _KernelChannelEngine, _MinMaxKernelEngine
+
+    q = JoinAggQuery(("R1", "R2", "R3"), (("R1", "g1"), ("R3", "g2")))
+    prep = prepare(q, measured_db())
+    return (
+        _KernelChannelEngine(prep, 2, {}, interpret=interpret),
+        {kind: _MinMaxKernelEngine(prep, kind, "R2", interpret=interpret)
+         for kind in ("min", "max")},
+    )
+
+
+def _hop(n, children, k=None, seed=0, knum=300):
+    """Random hop inputs: integer weights ``(n, k)`` (``(n,)`` when k is
+    None) that differ by channel, one ``(rows, width[, k])`` message per
+    child, CSR-sorted keys."""
+    rng = np.random.default_rng(seed)
+    chan = () if k is None else (k,)
+    weights = rng.integers(0, 9, (n,) + chan).astype(np.float64)
+    gathers = [
+        (rng.integers(0, 9, (rows, width) + chan).astype(np.float32),
+         rng.integers(0, rows, n))
+        for rows, width in children
+    ]
+    keys = np.sort(rng.integers(0, knum, n)).astype(np.int64)
+    return weights, gathers, keys, knum
+
+
+def _minmax_ref(kind, weights, gathers, keys, knum):
+    """The loop form of a MIN/MAX hop: candidate sums, then reduce."""
+    n = len(weights)
+    vals = weights.astype(np.float32).reshape(n, 1)
+    for m2, idx in gathers:
+        vals = (vals[:, :, None] + m2[idx][:, None, :]).reshape(n, -1)
+    red = np.minimum if kind == "min" else np.maximum
+    out = np.full((knum, vals.shape[1]), np.inf if kind == "min" else -np.inf,
+                  np.float32)
+    red.at(out, keys, vals)
+    return out
+
+
+SUM_HOPS = {
+    # COUNT + SUM weights differ by channel: no coo_spmm
+    "non-uniform": [(500, 7)],
+    "two-child": [(60, 3), (40, 5)],
+    "leaf": [],
+}
+
+
+@pytest.mark.parametrize("interpret", INTERPRET, ids=["pallas", "ref"])
+@pytest.mark.parametrize("n", EDGE_COUNTS)
+@pytest.mark.parametrize("hop", list(SUM_HOPS))
+def test_device_product_sum_hop_bit_identical(hop, n, interpret):
+    from repro.core.tensor_engine import ChannelTensorEngine
+
+    eng, _ = _engines(interpret)
+    weights, gathers, keys, knum = _hop(n, SUM_HOPS[hop], k=2)
+    got = eng._contract_block(weights, gathers, keys, knum)
+    want = ChannelTensorEngine._contract_block(
+        eng, weights, [(m.astype(np.float64), i) for m, i in gathers], keys, knum
+    )
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, want.astype(np.float32))
+
+
+@pytest.mark.parametrize("interpret", INTERPRET, ids=["pallas", "ref"])
+@pytest.mark.parametrize("n", EDGE_COUNTS)
+@pytest.mark.parametrize("kind", ["min", "max"])
+def test_device_product_minmax_hop_bit_identical(kind, n, interpret):
+    """Candidate sums over child messages whose unreached rows hold the
+    walk's identity, ``+inf`` for MIN and ``-inf`` for MAX, as a walk's
+    messages do."""
+    _, mm = _engines(interpret)
+    weights, gathers, keys, knum = _hop(n, [(90, 4), (30, 3)])
+    ident = np.inf if kind == "min" else -np.inf
+    for m2, _ in gathers:
+        m2[::4] = ident
+    got = mm[kind]._contract_block(weights, gathers, keys, knum)
+    want = _minmax_ref(kind, weights, gathers, keys, knum)
+    assert np.isinf(want).any() and np.isfinite(want).any()
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("interpret", INTERPRET, ids=["pallas", "ref"])
+def test_device_product_pad_rows_reading_inf_make_no_nan(interpret):
+    """The pad rows of the edge tail gather row 0 with weight 0; a child
+    message whose row 0 holds inf would make them 0 · inf = NaN, which the
+    one-hot ``segment_sum`` would spread over its tile.  The device zeroes
+    them, as the host pad did."""
+    from repro.core.tensor_engine import ChannelTensorEngine
+
+    eng, _ = _engines(interpret)
+    weights, gathers, keys, knum = _hop(1025, [(500, 7)], k=2)
+    m2, idx = gathers[0]
+    m2[0] = np.inf
+    idx[idx == 0] = 1  # no real edge reads row 0
+    got = eng._contract_block(weights, gathers, keys, knum)
+    want = ChannelTensorEngine._contract_block(eng, weights, gathers, keys, knum)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want.astype(np.float32))
+
+
+@pytest.mark.parametrize("kind", ["sum", "min", "max"])
+def test_hop_beyond_int32_keys_forms_its_product_on_the_host(kind, monkeypatch):
+    """Row keys past the kernels' int32 take the one host product, which
+    the host counter counts and the device counter does not."""
+    from repro import obs
+    from repro.core import jax_engine
+    from repro.core.tensor_engine import ChannelTensorEngine
+
+    eng, mm = _engines(True)
+    weights, gathers, keys, knum = _hop(
+        1025, [(60, 3)], k=2 if kind == "sum" else None
+    )
+    monkeypatch.setattr(jax_engine, "_INT32_LIMIT", knum)
+    obs.reset("hop.")
+    if kind == "sum":
+        got = eng._contract_block(weights, gathers, keys, knum)
+        want = ChannelTensorEngine._contract_block(
+            eng, weights, gathers, keys, knum
+        ).astype(np.float32)
+    else:
+        got = mm[kind]._contract_block(weights, gathers, keys, knum)
+        want = _minmax_ref(kind, weights, gathers, keys, knum)
+    np.testing.assert_array_equal(got, want)
+    c = obs.counters()
+    assert c["hop.product_edges_host"] == 1025
+    assert "hop.product_edges_device" not in c

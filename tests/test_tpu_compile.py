@@ -179,6 +179,39 @@ def test_fused_hop_compiles(compile_for_tpu, kind, k, hop):
     )
 
 
+# the benchmark's hops whose per-edge product ``hop_gather`` forms, padded
+# to the edge granule: (edges, child rows, child width, k, op)
+GATHER_HOPS = {
+    "agg-lineitem": (120832, 30000, 25, 2, "mul"),
+    "agg-customer-leaf": (3072, None, None, 2, "mul"),
+    "minmax-lineitem": (120832, 30000, 25, 1, "add"),
+    "minmax-orders": (30720, 3000, 25, 1, "add"),
+}
+
+
+@pytest.mark.parametrize("hop", list(GATHER_HOPS))
+def test_hop_gather_compiles(compile_for_tpu, hop):
+    """The device-side gather and product at the benchmark's hop shapes,
+    from 1-D uploads of the weights and child messages.  The program has
+    no kernel of its own, and its temporaries stay within twice the
+    product as HBM tiles it (rows of ``width·k`` padded to 128 lanes):
+    no intermediate keeps the narrow channel axis minor."""
+    from repro.core.jax_engine import hop_gather
+
+    sds, _ = compile_for_tpu
+    n, rows, width, k, op = GATHER_HOPS[hop]
+    children = [] if rows is None else [(rows, width)]
+    compiled = hop_gather.lower(
+        sds((n,), jnp.int32), sds((n * k,)),
+        tuple(sds((r * w * k,)) for r, w in children),
+        tuple(sds((n,), jnp.int32) for _ in children),
+        shapes=tuple(children), k=k, op=op,
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    product = 4 * n * ops.round_up((width or 1) * k, 128)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * product
+
+
 # ----------------------------------------------------------------------
 # kernel names: the label a device trace gives each custom call
 # ----------------------------------------------------------------------
